@@ -29,6 +29,7 @@ from benchmarks import (
     table1_overhead,
     table3_time_to_accuracy,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "cohort": cohort_bench.run,
@@ -55,6 +56,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="reduced rounds/sweeps")
     ap.add_argument("--only", default="", help="comma-separated bench names")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [n.strip() for n in args.only.split(",") if n.strip()] or list(BENCHES)
     print("name,us_per_call,derived")

@@ -149,9 +149,7 @@ def traced_restack() -> List[Violation]:
 def silent_f64() -> List[Violation]:
     """An f32 input promoted to f64 mid-program (x64 mode makes the
     promotion representable, exactly as a production x64 run would)."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: jnp.sum(x.astype(jnp.float64) * 2.0)
         )(jnp.zeros((4,), jnp.float32))

@@ -42,14 +42,18 @@ class RecompileBudgetExceeded(RuntimeError):
 
 
 class CompilationCounter:
-    """Context manager counting XLA backend compilations via jax.monitoring."""
+    """Context manager counting XLA backend compilations via jax.monitoring.
+
+    ``seconds`` sums the backend compile durations of those compilations."""
 
     def __init__(self):
         self.count = 0
+        self.seconds = 0.0
 
     def _listen(self, event: str, duration: float, **kw) -> None:
         if event == COMPILE_EVENT:
             self.count += 1
+            self.seconds += duration
 
     def __enter__(self) -> "CompilationCounter":
         from jax import monitoring
@@ -58,16 +62,9 @@ class CompilationCounter:
         return self
 
     def __exit__(self, *exc) -> bool:
-        try:
-            from jax._src import monitoring as _monitoring
+        from jax import monitoring
 
-            _monitoring._unregister_event_duration_listener_by_callback(
-                self._listen
-            )
-        except Exception:
-            # the private unregister helper moved; a stale listener only
-            # costs a no-op callback per compile, never correctness
-            pass
+        monitoring.unregister_event_duration_listener(self._listen)
         return False
 
 

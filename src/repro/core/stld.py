@@ -105,14 +105,26 @@ def sample_drops_block(key, rates, block_size: int, min_active: int = 1):
     return _force_min_active(drops, rates, min_active)
 
 
-def gate(block_fn: Callable, drop, h, cache=None):
+def gate(block_fn: Callable, drop, h, cache=None, *, select: bool = False):
     """The STLD gate: ``lax.cond(drop, identity, block_fn)``.
 
     ``block_fn(h, cache) -> (h', aux, cache')``; the identity branch passes
     ``h`` and ``cache`` through with aux = 0, so both branches have identical
     output structure (required by ``lax.cond``) and a skipped layer stores no
     activations for the backward pass — XLA executes only the taken branch.
+
+    ``select=True`` runs the block and picks its outputs or the identity with
+    ``jnp.where``.  That is the computation ``vmap`` of a ``cond`` with a
+    batched ``drop`` performs, but the vmapped cond also moves every operand
+    of the branches — the layer's frozen weights included — onto the batch
+    axis, one copy per batch member.  The select keeps the weights unbatched,
+    so vmapped callers (the batched cohort) pass ``select=True``.
     """
+    if select:
+        h_new, aux, cache_new = block_fn(h, cache)
+        keep = lambda new, old: jnp.where(drop, old, new)
+        aux = jnp.where(drop, jnp.zeros((), dtype=jnp.float32), aux)
+        return keep(h_new, h), aux, jax.tree.map(keep, cache_new, cache)
 
     def skip_branch(operands):
         h, cache = operands

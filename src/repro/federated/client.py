@@ -83,6 +83,14 @@ def make_client_fns(
     its fresh AdamW state, ``cohort_round_eval`` its stacked cohort PEFT
     input.  ``cohort_round`` never donates — its FedAdaOPT caller truncates
     against the start stack after the call returns.
+
+    Every training step rematerializes each layer in its backward pass, so
+    the saved activations are one layer input per layer.  The cohort
+    programs gate STLD with a select rather than a ``cond``
+    (``stld.gate(select=True)``): vmapping a ``cond`` whose predicate differs
+    per device would copy the frozen base weights once per cohort member.
+    The un-vmapped ``local_round`` keeps the ``cond``, so a dropped layer
+    skips its compute there.
     """
     if donate is None:
         donate = jax.default_backend() != "cpu"
@@ -92,7 +100,7 @@ def make_client_fns(
     )
     gather_mode = stld_cfg.mode == "gather"
 
-    def loss_fn(peft_params, base_params, tokens, targets, mask, drops, active_idx):
+    def loss_fn(peft_params, base_params, tokens, targets, mask, drops, active_idx, select_gates):
         logits, aux, _ = model_apply(
             base_params,
             cfg,
@@ -102,6 +110,8 @@ def make_client_fns(
             lora_scale=lora_sc,
             stack_mode="gather" if active_idx is not None else stack_mode,
             active_idx=active_idx,
+            remat=True,
+            select_gates=select_gates,
         )
         logits = _logits_for_tokens(cfg, logits, tokens)
         loss, metrics = softmax_xent(logits, targets, mask)
@@ -119,6 +129,7 @@ def make_client_fns(
         rng,
         global_step,
         num_active: Optional[int] = None,
+        select_gates: bool = False,
     ):
         shape = unit_shape(stld_cfg.distribution, cfg.num_layers)
         rates = jnp.clip(shape * mean_rate, 0.0, 0.95)
@@ -139,7 +150,7 @@ def make_client_fns(
                 active_idx = None
                 drops_for_imp = drops.astype(jnp.float32)
             (loss, metrics), grads = grad_fn(
-                peft_p, base_params, tokens, targets, mask, drops, active_idx
+                peft_p, base_params, tokens, targets, mask, drops, active_idx, select_gates
             )
             gnorms = ptls.layer_grad_norms(grads, cfg.num_layers)
             imp = ptls.ImportanceAccumulator.update(imp, gnorms, drops_for_imp)
@@ -172,7 +183,7 @@ def make_client_fns(
 
     local_round = jax.jit(
         _local_round,
-        static_argnames=("num_active",),
+        static_argnames=("num_active", "select_gates"),
         donate_argnums=(2,) if donate else (),  # the per-round AdamW state
     )
 
@@ -197,7 +208,8 @@ def make_client_fns(
         def one(peft_params, batches, rate, rng, gstep):
             opt0 = adamw_init(peft_params)
             peft_p, _, metrics, importance = _local_round(
-                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active
+                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active,
+                select_gates=True,
             )
             return peft_p, metrics, importance
 
@@ -268,7 +280,8 @@ def make_client_fns(
         def one(peft_params, batches, rate, rng, gstep, toks, labs, v):
             opt0 = adamw_init(peft_params)
             peft_p, _, metrics, importance = _local_round(
-                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active
+                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active,
+                select_gates=True,
             )
             acc = _masked_accuracy(base_params, peft_p, toks, labs, v, num_classes_arr)
             return peft_p, metrics, importance, acc

@@ -5,6 +5,7 @@ Each kernel ships three artifacts:
   * ``ops.py``    — jit'd public wrappers with shape plumbing + impl select
   * ``ref.py``    — pure-jnp oracles used by the allclose test sweeps
 
-On this CPU container kernels run in ``interpret=True`` mode (Pallas does not
-lower to the XLA CPU backend); on TPU the same code JITs natively.
+Every kernel's ``interpret=None`` default resolves by backend
+(``ops.is_cpu_backend``): interpret mode on the CPU (Pallas does not lower
+to the XLA CPU backend), the compiled Mosaic kernel on a TPU.
 """

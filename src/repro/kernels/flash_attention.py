@@ -107,12 +107,16 @@ def flash_attention_pallas(
     window: int | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret=None,
 ):
     """q: (B, H, S, D); k, v: (B, H, S, D) (GQA repeat done by the wrapper).
 
     Returns (B, H, S, D).
     """
+    if interpret is None:
+        from repro.kernels.ops import is_cpu_backend
+
+        interpret = is_cpu_backend()
     b, h, s, d = q.shape
     block_q = min(block_q, s)
     block_k = min(block_k, s)

@@ -84,10 +84,14 @@ def flash_decode_pallas(
     *,
     window: int | None = None,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret=None,
 ):
     """q: (B, H, D); k_cache/v_cache: (B, S, KV, D); k_positions: (S,) abs
     slot positions; q_position: int.  Returns (B, H, D)."""
+    if interpret is None:
+        from repro.kernels.ops import is_cpu_backend
+
+        interpret = is_cpu_backend()
     b, h, d = q.shape
     s, kv = k_cache.shape[1], k_cache.shape[2]
     rep = h // kv

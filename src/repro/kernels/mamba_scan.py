@@ -61,13 +61,17 @@ def mamba_scan_pallas(
     *,
     chunk: int = DEFAULT_CHUNK,
     d_block: int = DEFAULT_D_BLOCK,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Selective scan.
 
     dt, x: (B, S, D); bmat, cmat: (B, S, N); a: (D, N) (negative); dvec: (D,).
     Returns y (B, S, D) = C_t . h_t + D*x with h_t = exp(dt A) h_{t-1} + dt B x.
     """
+    if interpret is None:
+        from repro.kernels.ops import is_cpu_backend
+
+        interpret = is_cpu_backend()
     b, s, d = x.shape
     n = bmat.shape[-1]
     d_block = min(d_block, d)

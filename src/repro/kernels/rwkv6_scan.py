@@ -60,13 +60,17 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_scratch, *, chu
     )
 
 
-def wkv6_pallas(r, k, v, logw, u, *, chunk: int = DEFAULT_CHUNK, interpret: bool = True):
+def wkv6_pallas(r, k, v, logw, u, *, chunk: int = DEFAULT_CHUNK, interpret=None):
     """r,k,v,logw: (B, S, H, K); u: (H, K).  Returns out (B, S, H, K).
 
     logw must already be clamped to [-DECAY_CLAMP, 0) by the caller
     (``repro.nn.rwkv`` does this); the division trick inside the kernel is
     only numerically safe under that contract.
     """
+    if interpret is None:
+        from repro.kernels.ops import is_cpu_backend
+
+        interpret = is_cpu_backend()
     b, s, h, kd = r.shape
     s_pad = -(-s // chunk) * chunk
     if s_pad != s:

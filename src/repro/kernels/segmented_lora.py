@@ -26,7 +26,11 @@ the scale folded at swap time the traced program is dots + mask + add only.
 
 The grid is (M rows, N blocks) — decode batches are short (M = batch), so a
 one-row query block per adapter gather keeps the indexing exact; K is kept
-whole per block like ``lora_matmul``.
+whole per block like ``lora_matmul``.  Rows travel as ``(M, 1, K)`` /
+``(M, 1, N)`` so every block's last two dimensions equal the array's own
+(``(1, K)``, ``(1, block_n)``): Mosaic refuses a ``(1, K)`` block of an
+``(M, K)`` array for any M > 1, because a block's second-to-last dimension
+must be a multiple of 8 or span the whole axis.
 """
 from __future__ import annotations
 
@@ -41,7 +45,7 @@ DEFAULT_BLOCK_N = 128
 def _segmented_kernel(idx_ref, ranks_ref, x_ref, w_ref, a_ref, b_ref, o_ref):
     i = pl.program_id(0)
     slot = idx_ref[i]
-    x = x_ref[...]  # (1, K)
+    x = x_ref[0]  # (1, K)
     main = jax.lax.dot(x, w_ref[...], preferred_element_type=jnp.float32)
     t = jax.lax.dot(x, a_ref[0], preferred_element_type=jnp.float32)  # (1, r_max)
     # zero the padded rank tail: 2D iota (TPU requires >= 2D) vs this
@@ -49,7 +53,7 @@ def _segmented_kernel(idx_ref, ranks_ref, x_ref, w_ref, a_ref, b_ref, o_ref):
     rmask = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1) < ranks_ref[slot]
     t = jnp.where(rmask, t, 0.0)
     side = jax.lax.dot(t.astype(x.dtype), b_ref[0], preferred_element_type=jnp.float32)
-    o_ref[...] = (main + side).astype(o_ref.dtype)
+    o_ref[0] = (main + side).astype(o_ref.dtype)
 
 
 def segmented_lora_pallas(
@@ -83,24 +87,24 @@ def segmented_lora_pallas(
         num_scalar_prefetch=2,
         grid=(m, n_pad // block_n),
         in_specs=[
-            pl.BlockSpec((1, kdim), lambda i, j, idx, rk: (i, 0)),
+            pl.BlockSpec((1, 1, kdim), lambda i, j, idx, rk: (i, 0, 0)),
             pl.BlockSpec((kdim, block_n), lambda i, j, idx, rk: (0, j)),
             pl.BlockSpec((1, kdim, r_max), lambda i, j, idx, rk: (idx[i], 0, 0)),
             pl.BlockSpec((1, r_max, block_n), lambda i, j, idx, rk: (idx[i], 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda i, j, idx, rk: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, block_n), lambda i, j, idx, rk: (i, 0, j)),
     )
     out = pl.pallas_call(
         _segmented_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n_pad), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, 1, n_pad), x.dtype),
         interpret=interpret,
     )(
         idx.astype(jnp.int32),
         ranks.astype(jnp.int32),
-        x,
+        x[:, None, :],
         w,
         a,
         b,
     )
-    return out[:, :n]
+    return out[:, 0, :n]

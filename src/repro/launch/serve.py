@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, PEFTConfig, get_config
 from repro.core import peft as peft_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models.registry import init_params
 from repro.models.transformer import init_caches
@@ -94,6 +95,7 @@ def main():
                     help="serve the client adapters of a federated checkpoint")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(args.seed)

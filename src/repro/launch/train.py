@@ -27,6 +27,7 @@ from dataclasses import replace as dc_replace
 
 from repro import api
 from repro.federated.faults import FaultPlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.checkpoint import save_pytree
 from repro.configs import (
     ARCH_IDS,
@@ -95,6 +96,7 @@ def main():
                     help="resume from the newest run-state checkpoint")
     ap.add_argument("--out", default="results/train_history.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     fault_kw = {
         k: v

@@ -106,6 +106,8 @@ def decode(
     peft: Optional[Sequence] = None,
     lora_scale: float = 1.0,
     stack_mode: str = "unroll",
+    remat: bool = False,
+    select_gates: bool = False,
 ):
     """tokens: (B, S_dec).  Returns (logits, aux, new_caches)."""
     compute_dtype = jnp.dtype(cfg.dtype)
@@ -127,6 +129,8 @@ def decode(
         peft=peft,
         lora_scale=lora_scale,
         stack_mode=stack_mode,
+        remat=remat,
+        select_gates=select_gates,
     )
     h = _norm_apply(cfg, dec["final_norm"], h)
     logits = h @ dec["embed"].T.astype(compute_dtype)  # whisper ties output proj

@@ -50,6 +50,7 @@ def model_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
+    select_gates: bool = False,
 ):
     if cfg.is_encoder_decoder:
         if enc_kvs is None:
@@ -72,6 +73,8 @@ def model_apply(
             peft=peft,
             lora_scale=lora_scale,
             stack_mode=stack_mode if stack_mode in ("unroll", "scan") else "unroll",
+            remat=remat,
+            select_gates=select_gates,
         )
     prefix = batch.get("patches") if cfg.modality == "vision" else None
     return transformer.lm_apply(
@@ -87,6 +90,7 @@ def model_apply(
         stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
+        select_gates=select_gates,
     )
 
 

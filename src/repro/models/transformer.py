@@ -119,11 +119,14 @@ def stack_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
+    select_gates: bool = False,
 ):
     """Run the layer stack.  Returns (h, aux_sum, new_caches).
 
     ``layers``/``peft``/``enc_kvs`` accept either layout: a per-layer list
-    or a stacked tree with a leading layer axis.
+    or a stacked tree with a leading layer axis.  ``remat`` rematerializes
+    each layer in the backward pass; ``select_gates`` gates ``drops`` with
+    a select instead of a ``cond`` (see :func:`repro.core.stld.gate`).
     """
     num_layers = stacking.stack_size(layers)
 
@@ -155,7 +158,7 @@ def stack_apply(
             p_l = stacking.layer_view(layers, l)
             fn = lambda hh, cc, p=p_l, pf=peft_l, ek=enc_kv_l: block(p, pf, ek, hh, cc)
             if drops is not None:
-                h, aux, cache_l = stld.gate(fn, drops[l], h, cache_l)
+                h, aux, cache_l = stld.gate(fn, drops[l], h, cache_l, select=select_gates)
             else:
                 h, aux, cache_l = fn(h, cache_l)
             aux_sum = aux_sum + aux
@@ -212,7 +215,9 @@ def stack_apply(
             fn = lambda hh, cc: block(v["params"], v.get("peft"), v.get("enc"), hh, cc)
             cache_l = v.get("caches")
             if "drops" in v:
-                h, aux, new_cache = stld.gate(fn, v["drops"], h, cache_l)
+                h, aux, new_cache = stld.gate(
+                    fn, v["drops"], h, cache_l, select=select_gates
+                )
             else:
                 h, aux, new_cache = fn(h, cache_l)
             return h, (aux, new_cache if caches is not None else jnp.zeros((0,)))
@@ -269,7 +274,9 @@ def stack_apply(
                 peft_l = v["peft"][s] if "peft" in v else None
                 fn = lambda hh, cc, p=v["params"][s], pf=peft_l: block(p, pf, None, hh, cc)
                 if "drops" in v:
-                    h, aux, cache_l = stld.gate(fn, v["drops"][s], h, cache_l)
+                    h, aux, cache_l = stld.gate(
+                        fn, v["drops"][s], h, cache_l, select=select_gates
+                    )
                 else:
                     h, aux, cache_l = fn(h, cache_l)
                 aux_sum = aux_sum + aux
@@ -308,6 +315,7 @@ def lm_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
+    select_gates: bool = False,
 ):
     """Decoder-only LM forward.
 
@@ -334,6 +342,7 @@ def lm_apply(
         stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
+        select_gates=select_gates,
     )
     h = _norm_apply(cfg, params["final_norm"], h)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

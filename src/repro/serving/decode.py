@@ -47,8 +47,6 @@ def sharded_decode_attention(mesh, q, k_cache, v_cache, k_positions, q_position,
     ``axis``; k_positions: (S,) absolute slot positions (sharded alike).
     Returns (B, H, D) attention output, replicated.
     """
-    from jax.experimental.shard_map import shard_map
-
     def local(q, k, v, kpos):
         acc, m, l = _partial_attention(q, k, v, kpos, q_position, window)
         # log-sum-exp combine across sequence shards
@@ -58,12 +56,12 @@ def sharded_decode_attention(mesh, q, k_cache, v_cache, k_positions, q_position,
         acc_glob = jax.lax.psum(acc * scale[..., None], axis)
         return (acc_glob / jnp.maximum(l_glob, 1e-30)[..., None]).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None), P(axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, k_positions)
 
 

@@ -1,0 +1,112 @@
+"""Compiles for a described TPU v5e chip: what the chip's compiler refuses
+fails here, without a chip.
+
+Nothing runs: these tests lower and compile the main path's kernel and its
+largest training program at qwen3-1.7b's published widths, and read what the
+compiler reports.  The topology is described inside a module fixture, never
+at import, so that under pytest-xdist only the worker given this file loads
+the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import PEFTConfig, STLDConfig, TrainConfig, get_config
+from repro.core import peft as peft_lib
+from repro.federated.client import make_client_fns
+from repro.kernels.segmented_lora import segmented_lora_pallas
+from repro.models.registry import init_params
+
+GiB = 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no described chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _nbytes(tree) -> int:
+    return sum(
+        int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize for x in jax.tree.leaves(tree)
+    )
+
+
+@pytest.mark.parametrize("n", [2048, 1024], ids=["q", "v"])
+def test_segmented_lora_compiles_at_qwen3_width(one_chip, n):
+    """The serving kernel at qwen3-1.7b's q (2048) and v (1024) widths,
+    batch 4, rank pool 8: Mosaic accepts its block shapes."""
+    m, k, n_adapters, r_max = 4, 2048, 4, 8
+    fn = jax.jit(
+        lambda x, w, a, b, idx, ranks: segmented_lora_pallas(
+            x, w, a, b, idx, ranks, interpret=False
+        )
+    )
+    compiled = fn.lower(
+        _spec(one_chip, (m, k), jnp.bfloat16),
+        _spec(one_chip, (k, n), jnp.bfloat16),
+        _spec(one_chip, (n_adapters, k, r_max), jnp.bfloat16),
+        _spec(one_chip, (n_adapters, r_max, n), jnp.bfloat16),
+        _spec(one_chip, (m,), jnp.int32),
+        _spec(one_chip, (n_adapters,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cohort_round_eval_fits_one_chip(one_chip):
+    """The full-width cohort-4 train+eval program in cond-mode STLD: the
+    frozen base has one copy whatever the cohort, so arguments plus the
+    compiler's temp space stay under 15 GiB of the chip's 16 GB."""
+    cfg = get_config("qwen3-1.7b")
+    pcfg = PEFTConfig()
+    n, steps, batch, seq, val_pad = 4, 4, 16, 32, 64
+    spec = lambda tree, lead=(): jax.tree.map(
+        lambda x: _spec(one_chip, lead + x.shape, x.dtype), tree
+    )
+    base = spec(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    peft = spec(
+        jax.eval_shape(lambda: peft_lib.init_peft(jax.random.PRNGKey(1), cfg, pcfg)), (n,)
+    )
+    i32 = lambda *s: _spec(one_chip, s, jnp.int32)
+    f32 = lambda *s: _spec(one_chip, s, jnp.float32)
+    args = (
+        base,
+        peft,
+        {
+            "tokens": i32(n, steps, batch, seq),
+            "targets": i32(n, steps, batch, seq),
+            "mask": f32(n, steps, batch, seq),
+        },
+        f32(n),
+        _spec(one_chip, (n, 2), jnp.uint32),
+        i32(n),
+        i32(n, val_pad, seq),
+        i32(n, val_pad),
+        f32(n, val_pad),
+        i32(4),
+    )
+    fns = make_client_fns(
+        cfg, pcfg, STLDConfig(mode="cond"), TrainConfig(), stack_mode="scan", donate=True
+    )
+    compiled = fns.cohort_round_eval.lower(*args).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    total = _nbytes(args) + temp
+    assert total < 15 * GiB, f"args + temp = {total / GiB:.2f} GiB"

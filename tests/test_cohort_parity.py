@@ -119,3 +119,38 @@ def test_configurator_vector_rate_interface():
     for arm_rate in cfgor.arms:
         assert any(arm_rate == g for g in grid), f"drifted arm key {arm_rate!r}"
     assert cfgor.best_rate() in grid
+
+
+def test_cond_mode_gates_cond_alone_select_in_cohort():
+    """Cond-mode STLD: the un-vmapped local round keeps one real ``cond`` per
+    layer, so a dropped layer skips its compute there; the vmapped cohort
+    programs gate with a select, which keeps the frozen base weights off the
+    cohort axis (a vmapped cond would copy them once per device)."""
+    import jax.numpy as jnp
+
+    from repro.optim import adamw_init
+
+    runner = _runner("batched")
+    client = runner.ctx.engine.client
+    base = runner.ctx.engine.base_params
+    peft = runner.state.global_peft
+    n, s = 2, _FED.local_steps
+    shape = (s, _FED.batch_size, 8)
+    batch = {
+        "tokens": jnp.zeros(shape, jnp.int32),
+        "targets": jnp.zeros(shape, jnp.int32),
+        "mask": jnp.ones(shape, jnp.float32),
+    }
+    local = client.local_round.lower(
+        base, peft, adamw_init(peft), batch, jnp.float32(0.5),
+        jax.random.PRNGKey(0), jnp.int32(0),
+    ).as_text()
+    assert local.count("stablehlo.case") > 0
+    stack = lambda t: jax.tree.map(lambda x: jnp.stack([x] * n), t)
+    val = (jnp.zeros((n, 4, 8), jnp.int32), jnp.zeros((n, 4), jnp.int32), jnp.ones((n, 4)))
+    cohort = client.cohort_round_eval.lower(
+        base, stack(peft), stack(batch), jnp.full((n,), 0.5),
+        jax.random.split(jax.random.PRNGKey(0), n), jnp.zeros((n,), jnp.int32),
+        *val, runner.ctx.num_classes,
+    ).as_text()
+    assert cohort.count("stablehlo.case") == 0
