@@ -1,0 +1,10 @@
+"""Device milliseconds per round in the server's aggregation programs
+(PTLS share masks and masked means, FedAvg)."""
+from chipbench.trace import time_of
+
+
+def read(rec):
+    if rec.get("trace") is None or rec.get("round") is None or not rec["round"]["rounds"]:
+        return None
+    secs, n = time_of(rec["trace"], "modules", "ptls_aggregate", "cohort_shared_masks", "fedavg")
+    return secs * 1e3 / rec["round"]["rounds"] if n else None
