@@ -84,6 +84,11 @@ def make_client_fns(
     input.  ``cohort_round`` never donates — its FedAdaOPT caller truncates
     against the start stack after the call returns.
 
+    The local-step scan runs under the named scope ``client.train`` and the
+    fused validation of ``cohort_round_eval`` under ``client.validate``: the
+    names reach the compiled ops' ``op_name`` metadata, where a profiler
+    trace finds each phase's device time, and change nothing else.
+
     Every training step rematerializes each layer in its backward pass, so
     the saved activations are one layer input per layer.  The cohort
     programs gate STLD with a select rather than a ``cond``
@@ -174,9 +179,10 @@ def make_client_fns(
             return (peft_p, opt, imp, rng, gstep + 1), out_metrics
 
         xs = (batches["tokens"], batches["targets"], batches["mask"])
-        (peft_params, opt_state, imp, _, _), metrics = jax.lax.scan(
-            step, (peft_params, opt_state, imp0, rng, global_step), xs
-        )
+        with jax.named_scope("client.train"):
+            (peft_params, opt_state, imp, _, _), metrics = jax.lax.scan(
+                step, (peft_params, opt_state, imp0, rng, global_step), xs
+            )
         metrics = jax.tree.map(jnp.mean, metrics)
         importance = ptls.ImportanceAccumulator.importance(imp)
         return peft_params, opt_state, metrics, importance
@@ -283,7 +289,8 @@ def make_client_fns(
                 base_params, peft_params, opt0, batches, rate, rng, gstep, num_active,
                 select_gates=True,
             )
-            acc = _masked_accuracy(base_params, peft_p, toks, labs, v, num_classes_arr)
+            with jax.named_scope("client.validate"):
+                acc = _masked_accuracy(base_params, peft_p, toks, labs, v, num_classes_arr)
             return peft_p, metrics, importance, acc
 
         return jax.vmap(one)(

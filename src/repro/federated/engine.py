@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import stld as stld_lib
 from repro.federated import server as server_lib
 from repro.federated.client import make_client_fns
@@ -194,63 +195,83 @@ class CohortEngine:
             ]
         return [None] * len(rates)
 
+    def layer_bodies_per_step(self, rate: float) -> Optional[int]:
+        """Layer bodies one client step at ``rate`` runs: the static count in
+        gather mode, every layer under the batched cohort's select, and
+        ``None`` under the sequential ``cond``, which runs only the layers
+        its gates keep (the round's ``active_layers``)."""
+        na = self._static_active_counts([rate])[0]
+        if na is not None:
+            return na
+        return self.cfg.num_layers if self.cohort_mode == "batched" else None
+
     def _run_cohort_batched(
         self, cohort, rates, start_pefts, keys, gsteps, num_classes, adaopt_depth
     ):
-        """One (or few, in gather mode) jit'd calls train the whole cohort."""
+        """One (or few, in gather mode) jit'd calls train the whole cohort.
+
+        Host spans, inside the round's own: ``round.stage`` (stacking
+        and host-to-device copies), ``round.dispatch`` (enqueueing the
+        program) and ``round.pull`` (the unstack and the one ``device_get``
+        that waits for it)."""
         n = len(cohort)
         adaopt = adaopt_depth < self.cfg.num_layers
-        batch_list = [self._stacked_train_batches(dev) for dev in cohort]
-        val_list = [self._padded_val_batch(dev) for dev in cohort]
         num_active = self._static_active_counts(rates)
 
         outs: List[Optional[tuple]] = [None] * n
         for na in dict.fromkeys(num_active):
             pos = [i for i in range(n) if num_active[i] == na]
-            peft_stack = self._stack_trees([start_pefts[i] for i in pos])
-            batch_stack = {
-                k: jnp.asarray(np.stack([batch_list[i][k] for i in pos]))
-                for k in ("tokens", "targets", "mask")
-            }
-            rate_arr = jnp.asarray(np.asarray(rates, dtype=np.float32)[pos])
-            key_arr = jnp.stack([keys[i] for i in pos])
-            gstep_arr = jnp.asarray([gsteps[i] for i in pos], dtype=jnp.int32)
-            val_args = (
-                jnp.asarray(np.stack([val_list[i]["tokens"] for i in pos])),
-                jnp.asarray(np.stack([val_list[i]["labels"] for i in pos])),
-                jnp.asarray(np.stack([val_list[i]["valid"] for i in pos])),
-            )
-            if adaopt:
-                # progressive depth discards deep-layer updates before eval,
-                # so train and eval cannot be fused: train, truncate the
-                # stacked tree per layer, then evaluate the retained model
-                peft_out, metrics, importances = self.client.cohort_round(
-                    self.base_params, peft_stack, batch_stack,
-                    rate_arr, key_arr, gstep_arr, num_active=na,
+            with obs.span("round.stage"):
+                # each device samples from its own generator, so drawing
+                # group by group leaves every device's batches unchanged
+                batch_list = [self._stacked_train_batches(cohort[i]) for i in pos]
+                val_list = [self._padded_val_batch(cohort[i]) for i in pos]
+                peft_stack = self._stack_trees([start_pefts[i] for i in pos])
+                batch_stack = {
+                    k: jnp.asarray(np.stack([b[k] for b in batch_list]))
+                    for k in ("tokens", "targets", "mask")
+                }
+                rate_arr = jnp.asarray(np.asarray(rates, dtype=np.float32)[pos])
+                key_arr = jnp.stack([keys[i] for i in pos])
+                gstep_arr = jnp.asarray([gsteps[i] for i in pos], dtype=jnp.int32)
+                val_args = tuple(
+                    jnp.asarray(np.stack([v[k] for v in val_list]))
+                    for k in ("tokens", "labels", "valid")
                 )
-                peft_out = self._adaopt_truncate(
-                    peft_out, peft_stack, adaopt_depth,
-                    axis=0 if isinstance(peft_out, (list, tuple)) else 1,
-                )
-                accs = self.client.cohort_evaluate(
-                    self.base_params, peft_out, *val_args, num_classes
-                )
-            else:
-                peft_out, metrics, importances, accs = self.client.cohort_round_eval(
-                    self.base_params,
-                    peft_stack,
-                    batch_stack,
-                    rate_arr,
-                    key_arr,
-                    gstep_arr,
-                    *val_args,
-                    num_classes,
-                    num_active=na,
-                )
+            with obs.span("round.dispatch"):
+                if adaopt:
+                    # progressive depth discards deep-layer updates before
+                    # eval, so train and eval cannot be fused: train,
+                    # truncate the stacked tree per layer, then evaluate the
+                    # retained model
+                    peft_out, metrics, importances = self.client.cohort_round(
+                        self.base_params, peft_stack, batch_stack,
+                        rate_arr, key_arr, gstep_arr, num_active=na,
+                    )
+                    peft_out = self._adaopt_truncate(
+                        peft_out, peft_stack, adaopt_depth,
+                        axis=0 if isinstance(peft_out, (list, tuple)) else 1,
+                    )
+                    accs = self.client.cohort_evaluate(
+                        self.base_params, peft_out, *val_args, num_classes
+                    )
+                else:
+                    peft_out, metrics, importances, accs = self.client.cohort_round_eval(
+                        self.base_params,
+                        peft_stack,
+                        batch_stack,
+                        rate_arr,
+                        key_arr,
+                        gstep_arr,
+                        *val_args,
+                        num_classes,
+                        num_active=na,
+                    )
             # one jit'd unstack + one host pull: per-leaf x[j] slicing and
             # per-device float() syncs would cost hundreds of tiny dispatches
-            peft_list = self._unstack_tree(peft_out, len(pos))
-            metrics_np, imps_np, accs_np = jax.device_get((metrics, importances, accs))
+            with obs.span("round.pull"):
+                peft_list = self._unstack_tree(peft_out, len(pos))
+                metrics_np, imps_np, accs_np = jax.device_get((metrics, importances, accs))
             accs_list = np.asarray(accs_np).tolist()
             for j, i in enumerate(pos):
                 dev_metrics = {k: v[j] for k, v in metrics_np.items()}

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.core import peft as peft_lib
 from repro.data import DeviceDataset, dirichlet_partition, make_task
@@ -268,9 +269,10 @@ class ExperimentRunner:
             memory_gb=np.asarray([r["memory"] for r in hist]),
             arrivals=np.asarray([r.get("arrivals", -1) for r in hist]),
         )
-        res.final_accuracy = self.ctx.engine.final_accuracy(
-            self.state.global_peft, self.state.device_peft, self.ctx.num_classes
-        )
+        with obs.span("evaluate", round=self.state.round_index):
+            res.final_accuracy = self.ctx.engine.final_accuracy(
+                self.state.global_peft, self.state.device_peft, self.ctx.num_classes
+            )
         return res
 
     # --------------------------------------------------------- checkpointing

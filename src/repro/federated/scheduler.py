@@ -48,6 +48,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.federated import server as server_lib
 from repro.federated.faults import FaultInjector, ServerKilled
 from repro.federated.state import CohortResults, RoundPlan
@@ -272,7 +273,8 @@ class VirtualClockScheduler:
             # fault-aware event loop
             step = self._deadline_round
         while runner.state.round_index < total:
-            row = step(total, target_accuracy)
+            with obs.span("round", round=runner.state.round_index):
+                row = step(total, target_accuracy)
             hit_target = (
                 target_accuracy is not None and row["acc"] >= target_accuracy
             )
@@ -301,27 +303,30 @@ class VirtualClockScheduler:
         """Today's barrier round, hook for hook — the bit-parity anchor."""
         runner, algo = self.runner, self.runner.algorithm
         state = runner.state
-        plan = algo.configure_round(state)
-        plan.start_pefts = [algo.client_init(state, dev) for dev in plan.cohort]
+        with obs.span("round.configure"):
+            plan = algo.configure_round(state)
+            plan.start_pefts = [algo.client_init(state, dev) for dev in plan.cohort]
         state, results = algo.cohort_step(state, plan)
         state, results = algo.compress_uplink(state, results)
-        state = algo.aggregate(state, results)
-        state, row = algo.report(state, results)
-        t0 = runner.state.cum_time
-        state = replace(
-            state,
-            round_index=state.round_index + 1,
-            history=state.history + (row,),
-            virtual_time=state.cum_time,
-            server_version=state.server_version + 1,
-        )
-        runner.state = state
-        # log arrivals in event order for the determinism suite
-        times = np.asarray(results.cost.total_time_s).tolist()
-        for t, dev in sorted(
-            zip(times, plan.cohort), key=lambda p: (p[0], p[1])
-        ):
-            self.event_log.append((plan.round_index, dev, t0 + t))
+        with obs.span("round.aggregate"):
+            state = algo.aggregate(state, results)
+        with obs.span("round.report"):
+            state, row = algo.report(state, results)
+            t0 = runner.state.cum_time
+            state = replace(
+                state,
+                round_index=state.round_index + 1,
+                history=state.history + (row,),
+                virtual_time=state.cum_time,
+                server_version=state.server_version + 1,
+            )
+            runner.state = state
+            # log arrivals in event order for the determinism suite
+            times = np.asarray(results.cost.total_time_s).tolist()
+            for t, dev in sorted(
+                zip(times, plan.cohort), key=lambda p: (p[0], p[1])
+            ):
+                self.event_log.append((plan.round_index, dev, t0 + t))
         return row
 
     # ------------------------------------------------------------- dispatch

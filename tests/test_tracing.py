@@ -1,0 +1,140 @@
+"""The program's own tracing (``repro.obs``): the host spans of a sync round,
+the layer bodies a client step runs in each gating mode, and the named scopes
+of the client programs, which add metadata and nothing else."""
+import contextlib
+import re
+
+import jax
+import pytest
+from jax.profiler import ProfileData
+
+from repro import api
+from repro.configs import FederatedConfig, PEFTConfig, STLDConfig, TrainConfig, get_config
+from repro.federated.client import make_client_fns
+
+_CFG = get_config("qwen3-1.7b", smoke=True).replace(
+    num_layers=4, d_model=32, d_ff=64, num_heads=2, num_kv_heads=2,
+    vocab_size=128, dtype="float32",
+)
+_FED = FederatedConfig(num_devices=6, devices_per_round=3, local_steps=2, batch_size=4)
+_TRAIN = TrainConfig(learning_rate=5e-3, total_steps=100, warmup_steps=2)
+_PEFT = PEFTConfig(method="lora", lora_rank=2)
+PHASES = ["configure", "stage", "dispatch", "pull", "aggregate", "report"]
+
+
+def _runner(rate, seed=3, mode="cond", cohort_mode="batched"):
+    return api.build(
+        "droppeft",
+        cfg=_CFG,
+        peft_cfg=_PEFT,
+        stld_cfg=STLDConfig(mode=mode, mean_rate=rate, gather_bucket=1),
+        fixed_rate=rate,
+        fed_cfg=_FED,
+        train_cfg=_TRAIN,
+        schedule="sync",
+        seed=seed,
+        cohort_mode=cohort_mode,
+    )
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Two sync rounds at rate 0.5 under the profiler; the runner and the
+    program's host spans, ``(name, start_ns, end_ns, args)`` in order of
+    start."""
+    runner = _runner(0.5)
+    out = tmp_path_factory.mktemp("trace")
+    with jax.profiler.trace(str(out)):
+        runner.run(rounds=2)
+    (path,) = out.rglob("*.xplane.pb")
+    spans = sorted(
+        ((e.name, e.start_ns, e.end_ns, dict(e.stats))
+         for plane in ProfileData.from_file(str(path)).planes
+         if plane.name.startswith("/host")
+         for line in plane.lines for e in line.events if e.name.startswith("repro.")),
+        key=lambda s: s[1],
+    )
+    return runner, spans
+
+
+def test_each_round_span_holds_its_phases_in_order(traced):
+    _, spans = traced
+    rounds = [s for s in spans if s[0] == "repro.round"]
+    assert [s[3] for s in rounds] == [{"round": 0}, {"round": 1}]
+    for _, r0, r1, _ in rounds:
+        inside = [s for s in spans if r0 <= s[1] and s[2] <= r1 and s[0] != "repro.round"]
+        assert [s[0] for s in inside] == [f"repro.round.{p}" for p in PHASES]
+        # the phases take their round from the span around them
+        assert all(s[3] == {} for s in inside)
+    # the evaluation that ends the run call follows the rounds
+    (evaluate,) = [s for s in spans if s[0] == "repro.evaluate"]
+    assert evaluate[1] >= rounds[-1][2]
+    assert evaluate[3] == {"round": 2}
+
+
+def test_kept_layers_within_the_bodies_run(traced):
+    runner, _ = traced
+    bodies = runner.ctx.engine.layer_bodies_per_step(0.5)
+    # the batched cohort gates with a select: every layer body runs
+    assert bodies == _CFG.num_layers
+    for row in runner.state.history:
+        assert 0 < row["active"] * _CFG.num_layers <= bodies
+
+
+@pytest.mark.parametrize(
+    "mode,cohort_mode,rate,bodies",
+    [
+        ("cond", "batched", 0.0, _CFG.num_layers),
+        ("gather", "batched", 0.5, 2),  # round(4 x 0.5), bucket 1
+        ("cond", "sequential", 0.5, None),  # the cond runs only the kept layers
+    ],
+)
+def test_layer_bodies_per_step_follows_the_gating_mode(mode, cohort_mode, rate, bodies):
+    engine = _runner(rate, mode=mode, cohort_mode=cohort_mode).ctx.engine
+    assert engine.layer_bodies_per_step(rate) == bodies
+
+
+_METADATA = re.compile(r",? metadata=\{[^}]*\}")
+_NAME = re.compile(r"%[\w.\-]+")
+
+
+def _program(text):
+    """Optimized HLO text without its metadata, and with every instruction
+    and computation name replaced by its order of first appearance: a named
+    scope can move the number the name uniquifier appends to a few names."""
+    names = {}
+    text = _METADATA.sub("", text)
+    return _NAME.sub(lambda m: names.setdefault(m.group(), f"%n{len(names)}"), text)
+
+
+def _cohort_round_eval_text(runner):
+    """The optimized HLO of the runner's batched ``cohort_round_eval``, built
+    anew from ``make_client_fns`` at the arguments one round passes."""
+    engine = runner.ctx.engine
+    seen = {}
+    fn = engine.client.cohort_round_eval
+
+    def keep(*a, **kw):
+        seen.setdefault("args", (a, kw))
+        return fn(*a, **kw)
+
+    engine.client = engine.client._replace(cohort_round_eval=keep)
+    runner.run(rounds=1)
+    a, kw = seen["args"]
+    client = make_client_fns(
+        _CFG, _PEFT, runner.ctx.stld_cfg, _TRAIN, stack_mode=engine.stack_mode)
+    return client.cohort_round_eval.lower(*a, **kw).compile().as_text()
+
+
+def test_scopes_name_the_phases_and_change_nothing_else(monkeypatch):
+    texts = {}
+    for scoped in (True, False):  # one call site, so the stack frames match
+        with monkeypatch.context() as m:
+            if not scoped:
+                m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+            texts[scoped] = _cohort_round_eval_text(_runner(0.5))
+    op_names = re.findall(r'op_name="([^"]*)"', texts[True])
+    assert any("client.train" in n for n in op_names)
+    assert any("client.validate" in n for n in op_names)
+    assert "client.train" not in texts[False] and "client.validate" not in texts[False]
+    assert _program(texts[True]) == _program(texts[False])
