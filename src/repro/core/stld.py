@@ -39,6 +39,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.custom_derivatives import SymbolicZero
 
 
 def expected_active_layers(rates) -> jnp.ndarray:
@@ -105,7 +106,7 @@ def sample_drops_block(key, rates, block_size: int, min_active: int = 1):
     return _force_min_active(drops, rates, min_active)
 
 
-def gate(block_fn: Callable, drop, h, cache=None, *, select: bool = False):
+def gate(block_fn: Callable, drop, h, cache=None):
     """The STLD gate: ``lax.cond(drop, identity, block_fn)``.
 
     ``block_fn(h, cache) -> (h', aux, cache')``; the identity branch passes
@@ -113,18 +114,10 @@ def gate(block_fn: Callable, drop, h, cache=None, *, select: bool = False):
     output structure (required by ``lax.cond``) and a skipped layer stores no
     activations for the backward pass — XLA executes only the taken branch.
 
-    ``select=True`` runs the block and picks its outputs or the identity with
-    ``jnp.where``.  That is the computation ``vmap`` of a ``cond`` with a
-    batched ``drop`` performs, but the vmapped cond also moves every operand
-    of the branches — the layer's frozen weights included — onto the batch
-    axis, one copy per batch member.  The select keeps the weights unbatched,
-    so vmapped callers (the batched cohort) pass ``select=True``.
+    ``drop`` must be unbatched: under ``vmap`` a ``cond`` with a batched
+    predicate becomes a select that runs the block for every member, so the
+    batched cohort maps its clients in turn (``repro.federated.client``).
     """
-    if select:
-        h_new, aux, cache_new = block_fn(h, cache)
-        keep = lambda new, old: jnp.where(drop, old, new)
-        aux = jnp.where(drop, jnp.zeros((), dtype=jnp.float32), aux)
-        return keep(h_new, h), aux, jax.tree.map(keep, cache_new, cache)
 
     def skip_branch(operands):
         h, cache = operands
@@ -135,3 +128,51 @@ def gate(block_fn: Callable, drop, h, cache=None, *, select: bool = False):
         return block_fn(h, cache)
 
     return jax.lax.cond(drop, skip_branch, active_branch, (h, cache))
+
+
+def gate_remat(block_fn: Callable, drop, h, frozen, trained):
+    """:func:`gate` for training, rematerialized as one unit.
+
+    ``block_fn(h, frozen, trained) -> (h', aux)``.  The forward runs the gate
+    and saves only its inputs.  The backward is one ``cond``: the kept
+    branch recomputes the block and takes its VJP in the same branch, the
+    dropped branch passes the cotangent of ``h`` through.  A dropped layer
+    so costs nothing either way, and no weight or activation crosses a
+    branch boundary: ``jax.checkpoint`` around :func:`gate` would split the
+    backward into a recompute ``cond`` and a VJP ``cond``, the first writing
+    every weight slice and activation the second reads (zeros, when the
+    layer is dropped).  ``frozen`` (the layer's weights) takes no gradient,
+    and differentiating it raises; ``trained`` (its adapters) does.
+    """
+
+    def run(drop, h, frozen, trained):
+        h_new, aux, _ = gate(lambda hh, cc: (*block_fn(hh, frozen, trained), cc), drop, h)
+        return h_new, aux
+
+    def fwd(drop, h, frozen, trained):
+        if any(p.perturbed for p in jax.tree.leaves(frozen)):
+            raise ValueError("the frozen layer weights take no gradient through gate_remat")
+        res = jax.tree.map(lambda p: p.value, (drop, h, frozen, trained))
+        return run(*res), res
+
+    def bwd(res, cts):
+        drop, h, frozen, trained = res
+        cts = jax.tree.map(
+            lambda c: jnp.zeros(c.aval.shape, c.aval.dtype) if isinstance(c, SymbolicZero) else c,
+            cts,
+            is_leaf=lambda c: isinstance(c, SymbolicZero),
+        )
+
+        def kept(cts):
+            _, vjp = jax.vjp(lambda hh, tt: block_fn(hh, frozen, tt), h, trained)
+            return vjp(cts)
+
+        def dropped(cts):
+            return cts[0], jax.tree.map(jnp.zeros_like, trained)
+
+        dh, dtrained = jax.lax.cond(drop, dropped, kept, cts)
+        return None, dh, None, dtrained
+
+    gated = jax.custom_vjp(run)
+    gated.defvjp(fwd, bwd, symbolic_zeros=True)
+    return gated(drop, h, frozen, trained)

@@ -9,12 +9,12 @@
   the Eq.-6 PTLS importance statistics.
 * ``evaluate``     — full-model (no dropout) classification accuracy on the
   device's local validation split.
-* ``cohort_round`` — the batched cohort engine: ``jax.vmap`` of the local
-  round over a leading device axis.  One jit'd call trains a whole cohort
-  from stacked per-device batches, a per-device ``mean_rate`` vector, split
-  PRNG keys, and per-device global-step offsets.  Each device starts from a
-  fresh AdamW state (exactly what the simulator does per round), so the
-  optimizer state never crosses the device axis.
+* ``cohort_round`` — the batched cohort engine: the local round over a
+  leading device axis, the devices in turn (``lax.map``).  One jit'd call
+  trains a whole cohort from stacked per-device batches, a per-device ``mean_rate``
+  vector, split PRNG keys, and per-device global-step offsets.  Each device
+  starts from a fresh AdamW state (exactly what the simulator does per
+  round), so the optimizer state never crosses the device axis.
 * ``cohort_evaluate`` — vmapped validation over the device axis.  Val shards
   have heterogeneous sizes, so batches arrive padded to a common size with a
   ``valid`` row mask; the masked mean equals the per-device plain mean.
@@ -90,12 +90,13 @@ def make_client_fns(
     trace finds each phase's device time, and change nothing else.
 
     Every training step rematerializes each layer in its backward pass, so
-    the saved activations are one layer input per layer.  The cohort
-    programs gate STLD with a select rather than a ``cond``
-    (``stld.gate(select=True)``): vmapping a ``cond`` whose predicate differs
-    per device would copy the frozen base weights once per cohort member.
-    The un-vmapped ``local_round`` keeps the ``cond``, so a dropped layer
-    skips its compute there.
+    the saved activations are one layer input per layer.  With STLD enabled
+    every program gates each layer with a real ``cond``, so a dropped layer
+    costs no forward, recompute or backward; with it disabled the layers run
+    ungated.  The cohort programs train their devices one after another
+    inside the one program, since under ``vmap`` a ``cond`` whose predicate
+    differs per device becomes a select that runs every layer.  The fused
+    validation is vmapped over the trained trees.
     """
     if donate is None:
         donate = jax.default_backend() != "cpu"
@@ -105,7 +106,7 @@ def make_client_fns(
     )
     gather_mode = stld_cfg.mode == "gather"
 
-    def loss_fn(peft_params, base_params, tokens, targets, mask, drops, active_idx, select_gates):
+    def loss_fn(peft_params, base_params, tokens, targets, mask, drops, active_idx):
         logits, aux, _ = model_apply(
             base_params,
             cfg,
@@ -116,7 +117,6 @@ def make_client_fns(
             stack_mode="gather" if active_idx is not None else stack_mode,
             active_idx=active_idx,
             remat=True,
-            select_gates=select_gates,
         )
         logits = _logits_for_tokens(cfg, logits, tokens)
         loss, metrics = softmax_xent(logits, targets, mask)
@@ -134,7 +134,6 @@ def make_client_fns(
         rng,
         global_step,
         num_active: Optional[int] = None,
-        select_gates: bool = False,
     ):
         shape = unit_shape(stld_cfg.distribution, cfg.num_layers)
         rates = jnp.clip(shape * mean_rate, 0.0, 0.95)
@@ -154,8 +153,10 @@ def make_client_fns(
                 drops = stld.sample_drops(kd, rates, stld_cfg.min_active_layers)
                 active_idx = None
                 drops_for_imp = drops.astype(jnp.float32)
+            # disabled STLD draws all-kept gates; the layers then run ungated
+            gates = drops if stld_cfg.enabled else None
             (loss, metrics), grads = grad_fn(
-                peft_p, base_params, tokens, targets, mask, drops, active_idx, select_gates
+                peft_p, base_params, tokens, targets, mask, gates, active_idx
             )
             gnorms = ptls.layer_grad_norms(grads, cfg.num_layers)
             imp = ptls.ImportanceAccumulator.update(imp, gnorms, drops_for_imp)
@@ -189,9 +190,24 @@ def make_client_fns(
 
     local_round = jax.jit(
         _local_round,
-        static_argnames=("num_active", "select_gates"),
+        static_argnames=("num_active",),
         donate_argnums=(2,) if donate else (),  # the per-round AdamW state
     )
+
+    def _train_cohort(base_params, peft_stack, batch_stack, rates, rngs, global_steps, num_active):
+        """The local round of every cohort member, one after another
+        (``lax.map``), so each member's gates are scalars and stay real
+        ``cond``s.  Returns the stacked results."""
+
+        def one(xs):
+            peft_params, batches, rate, rng, gstep = xs
+            opt0 = adamw_init(peft_params)
+            peft_p, _, metrics, importance = _local_round(
+                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active
+            )
+            return peft_p, metrics, importance
+
+        return jax.lax.map(one, (peft_stack, batch_stack, rates, rngs, global_steps))
 
     @partial(jax.jit, static_argnames=("num_active",))
     def cohort_round(
@@ -203,23 +219,16 @@ def make_client_fns(
         global_steps,   # (N,) per-device LR-schedule offsets
         num_active: Optional[int] = None,
     ):
-        """Train the whole cohort in one call: vmap of ``local_round``.
+        """Train the whole cohort in one call: ``local_round`` per device.
 
         ``num_active`` is static (gather mode); a cohort with heterogeneous
         static counts must be partitioned into same-count groups by the
         caller (the simulator does this).  Returns stacked
         ``(peft_stack, metrics, importances)``.
         """
-
-        def one(peft_params, batches, rate, rng, gstep):
-            opt0 = adamw_init(peft_params)
-            peft_p, _, metrics, importance = _local_round(
-                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active,
-                select_gates=True,
-            )
-            return peft_p, metrics, importance
-
-        return jax.vmap(one)(peft_stack, batch_stack, rates, rngs, global_steps)
+        return _train_cohort(
+            base_params, peft_stack, batch_stack, rates, rngs, global_steps, num_active
+        )
 
     def _class_logits(base_params, peft_params, tokens, num_classes_arr):
         """Label-token logits at the final position (synthetic task protocol)."""
@@ -281,21 +290,17 @@ def make_client_fns(
     ):
         """Fused cohort train + validation: one dispatch per round so the
         per-call overhead (arg flattening of the ~100-leaf base tree, program
-        launch) is paid once for the whole cohort instead of 2N times."""
-
-        def one(peft_params, batches, rate, rng, gstep, toks, labs, v):
-            opt0 = adamw_init(peft_params)
-            peft_p, _, metrics, importance = _local_round(
-                base_params, peft_params, opt0, batches, rate, rng, gstep, num_active,
-                select_gates=True,
-            )
-            with jax.named_scope("client.validate"):
-                acc = _masked_accuracy(base_params, peft_p, toks, labs, v, num_classes_arr)
-            return peft_p, metrics, importance, acc
-
-        return jax.vmap(one)(
-            peft_stack, batch_stack, rates, rngs, global_steps,
-            val_tokens, val_labels, val_valid,
+        launch) is paid once for the whole cohort instead of 2N times.  The
+        validation of the trained trees is vmapped over the cohort."""
+        peft_out, metrics, importances = _train_cohort(
+            base_params, peft_stack, batch_stack, rates, rngs, global_steps, num_active
         )
+
+        def accuracy(peft_params, toks, labs, v):
+            return _masked_accuracy(base_params, peft_params, toks, labs, v, num_classes_arr)
+
+        with jax.named_scope("client.validate"):
+            accs = jax.vmap(accuracy)(peft_out, val_tokens, val_labels, val_valid)
+        return peft_out, metrics, importances, accs
 
     return ClientFns(local_round, evaluate, cohort_round, cohort_evaluate, cohort_round_eval)

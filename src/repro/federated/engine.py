@@ -9,11 +9,12 @@ per-device datasets, and the batched/sequential dispatch strategy, while the
 
 * ``"batched"`` — per-device batches, dropout rates, PRNG keys and
   LR-schedule offsets are stacked along a leading device axis and one jit'd
-  ``cohort_round`` (``jax.vmap`` of the local round) trains the whole
-  cohort; validation runs through the vmapped ``cohort_evaluate`` on padded
-  val batches.  In gather-mode STLD the static active-layer count can
-  differ per device, so the cohort is partitioned into same-count groups
-  and each group runs as one batched call.
+  ``cohort_round`` trains the whole cohort, the devices in turn inside the
+  program, so each STLD gate is a real ``cond`` and a dropped layer runs
+  nothing.  Validation runs vmapped on padded val batches.  In gather-mode
+  STLD the static active-layer count can differ per device, so the cohort
+  is partitioned into same-count groups and each group runs as one
+  batched call.
 * ``"sequential"`` — the per-device python loop, one jit'd ``local_round``
   dispatch per device.  Required for FedHetLoRA's rank-heterogeneous PEFT
   trees, which cannot share one stacked vmap axis.
@@ -196,14 +197,15 @@ class CohortEngine:
         return [None] * len(rates)
 
     def layer_bodies_per_step(self, rate: float) -> Optional[int]:
-        """Layer bodies one client step at ``rate`` runs: the static count in
-        gather mode, every layer under the batched cohort's select, and
-        ``None`` under the sequential ``cond``, which runs only the layers
-        its gates keep (the round's ``active_layers``)."""
+        """Layer bodies one client step at ``rate`` runs, batched or
+        sequential alike: the static count in gather mode; ``None`` under the
+        ``cond`` gates, which run only the layers they keep (the round's
+        ``active_layers``); every layer when the STLD config is off, since
+        the client programs then build no gates."""
         na = self._static_active_counts([rate])[0]
         if na is not None:
             return na
-        return self.cfg.num_layers if self.cohort_mode == "batched" else None
+        return None if self.stld_cfg.enabled else self.cfg.num_layers
 
     def _run_cohort_batched(
         self, cohort, rates, start_pefts, keys, gsteps, num_classes, adaopt_depth

@@ -107,7 +107,6 @@ def decode(
     lora_scale: float = 1.0,
     stack_mode: str = "unroll",
     remat: bool = False,
-    select_gates: bool = False,
 ):
     """tokens: (B, S_dec).  Returns (logits, aux, new_caches)."""
     compute_dtype = jnp.dtype(cfg.dtype)
@@ -130,7 +129,6 @@ def decode(
         lora_scale=lora_scale,
         stack_mode=stack_mode,
         remat=remat,
-        select_gates=select_gates,
     )
     h = _norm_apply(cfg, dec["final_norm"], h)
     logits = h @ dec["embed"].T.astype(compute_dtype)  # whisper ties output proj

@@ -50,7 +50,6 @@ def model_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
-    select_gates: bool = False,
 ):
     if cfg.is_encoder_decoder:
         if enc_kvs is None:
@@ -74,7 +73,6 @@ def model_apply(
             lora_scale=lora_scale,
             stack_mode=stack_mode if stack_mode in ("unroll", "scan") else "unroll",
             remat=remat,
-            select_gates=select_gates,
         )
     prefix = batch.get("patches") if cfg.modality == "vision" else None
     return transformer.lm_apply(
@@ -90,7 +88,6 @@ def model_apply(
         stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
-        select_gates=select_gates,
     )
 
 

@@ -119,32 +119,50 @@ def stack_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
-    select_gates: bool = False,
 ):
     """Run the layer stack.  Returns (h, aux_sum, new_caches).
 
     ``layers``/``peft``/``enc_kvs`` accept either layout: a per-layer list
     or a stacked tree with a leading layer axis.  ``remat`` rematerializes
-    each layer in the backward pass; ``select_gates`` gates ``drops`` with
-    a select instead of a ``cond`` (see :func:`repro.core.stld.gate`).
+    each layer in the backward pass; with ``drops`` the gate and the block
+    are rematerialized as one unit (:func:`repro.core.stld.gate_remat`), so
+    a layer saves only its input, a dropped layer costs no backward work,
+    and no gradient reaches the layer weights.  A ``cond`` around a
+    checkpointed block would save the checkpoint's inputs, the layer's
+    weights among them, and a layer scan would stack them into a copy of
+    every layer's weights.
     """
     num_layers = stacking.stack_size(layers)
 
-    def block(p_l, peft_l, enc_kv_l, h, cache_l):
-        fn = lambda hh, cc: layer_apply(
-            p_l,
-            cfg,
-            hh,
-            positions=positions,
-            causal=causal,
-            cache=cc,
-            enc_kv=enc_kv_l,
-            peft=peft_l,
-            lora_scale=lora_scale,
-        )
+    def layer(p_l, peft_l, enc_kv_l, h, cache_l, drop):
+        # every array the block reads is an argument, so that the remat
+        # gate's backward closes over none
+        def block(hh, frozen, pf, cc=None):
+            p, enc_kv, pos = frozen
+            return layer_apply(
+                p,
+                cfg,
+                hh,
+                positions=pos,
+                causal=causal,
+                cache=cc,
+                enc_kv=enc_kv,
+                peft=pf,
+                lora_scale=lora_scale,
+            )
+
+        frozen = (p_l, enc_kv_l, positions)
+        if drop is not None and remat:
+            if cache_l is not None:
+                raise ValueError("remat with STLD gates runs without caches")
+            train = lambda hh, fz, pf: block(hh, fz, pf)[:2]
+            return (*stld.gate_remat(train, drop, h, frozen, peft_l), None)
+        fn = lambda hh, cc: block(hh, frozen, peft_l, cc)
         if remat:
             fn = jax.checkpoint(fn)
-        return fn(h, cache_l)
+        if drop is None:
+            return fn(h, cache_l)
+        return stld.gate(fn, drop, h, cache_l)
 
     # ---------------------------------------------------------- unroll
     if stack_mode == "unroll":
@@ -156,11 +174,8 @@ def stack_apply(
             peft_l = stacking.layer_view(peft, l) if peft is not None else None
             enc_kv_l = stacking.layer_view(enc_kvs, l) if enc_kvs is not None else None
             p_l = stacking.layer_view(layers, l)
-            fn = lambda hh, cc, p=p_l, pf=peft_l, ek=enc_kv_l: block(p, pf, ek, hh, cc)
-            if drops is not None:
-                h, aux, cache_l = stld.gate(fn, drops[l], h, cache_l, select=select_gates)
-            else:
-                h, aux, cache_l = fn(h, cache_l)
+            drop = drops[l] if drops is not None else None
+            h, aux, cache_l = layer(p_l, peft_l, enc_kv_l, h, cache_l, drop)
             aux_sum = aux_sum + aux
             if new_caches is not None:
                 new_caches.append(cache_l)
@@ -184,7 +199,7 @@ def stack_apply(
             idx = active_idx[j]
             p_l = take(stacked, idx)
             peft_l = take(peft_s, idx) if peft_s is not None else None
-            h, aux, _ = block(p_l, peft_l, None, h, None)
+            h, aux, _ = layer(p_l, peft_l, None, h, None, None)
             aux_sum = aux_sum + aux
         return h, aux_sum, None
 
@@ -212,14 +227,9 @@ def stack_apply(
 
         def body(h, xs_vals):
             v = dict(zip(order, xs_vals))
-            fn = lambda hh, cc: block(v["params"], v.get("peft"), v.get("enc"), hh, cc)
-            cache_l = v.get("caches")
-            if "drops" in v:
-                h, aux, new_cache = stld.gate(
-                    fn, v["drops"], h, cache_l, select=select_gates
-                )
-            else:
-                h, aux, new_cache = fn(h, cache_l)
+            h, aux, new_cache = layer(
+                v["params"], v.get("peft"), v.get("enc"), h, v.get("caches"), v.get("drops")
+            )
             return h, (aux, new_cache if caches is not None else jnp.zeros((0,)))
 
         h, (auxs, new_caches_s) = jax.lax.scan(body, h, xs)
@@ -272,13 +282,8 @@ def stack_apply(
             for s in range(period):
                 cache_l = v["caches"][s] if "caches" in v else None
                 peft_l = v["peft"][s] if "peft" in v else None
-                fn = lambda hh, cc, p=v["params"][s], pf=peft_l: block(p, pf, None, hh, cc)
-                if "drops" in v:
-                    h, aux, cache_l = stld.gate(
-                        fn, v["drops"][s], h, cache_l, select=select_gates
-                    )
-                else:
-                    h, aux, cache_l = fn(h, cache_l)
+                drop = v["drops"][s] if "drops" in v else None
+                h, aux, cache_l = layer(v["params"][s], peft_l, None, h, cache_l, drop)
                 aux_sum = aux_sum + aux
                 out_caches.append(cache_l if cache_l is not None else jnp.zeros((0,)))
             return h, (aux_sum, tuple(out_caches))
@@ -315,7 +320,6 @@ def lm_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
-    select_gates: bool = False,
 ):
     """Decoder-only LM forward.
 
@@ -342,7 +346,6 @@ def lm_apply(
         stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
-        select_gates=select_gates,
     )
     h = _norm_apply(cfg, params["final_norm"], h)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -71,13 +71,24 @@ def test_segmented_lora_compiles_at_qwen3_width(one_chip, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_cohort_round_eval_fits_one_chip(one_chip):
+@pytest.mark.parametrize(
+    "batch,seq,limit_gib",
+    [
+        (16, 32, 15.0),
+        # the round cell's shape: the cohort in turn compiles at 11.40 GiB
+        # there, where the select-gated program took 14.71; a layer weight
+        # copy per cohort member or per layer would pass 12
+        (8, 128, 12.0),
+    ],
+    ids=["b16s32", "cell"],
+)
+def test_cohort_round_eval_fits_one_chip(one_chip, batch, seq, limit_gib):
     """The full-width cohort-4 train+eval program in cond-mode STLD: the
     frozen base has one copy whatever the cohort, so arguments plus the
-    compiler's temp space stay under 15 GiB of the chip's 16 GB."""
+    compiler's temp space stay under the limit of the chip's 16 GB."""
     cfg = get_config("qwen3-1.7b")
     pcfg = PEFTConfig()
-    n, steps, batch, seq, val_pad = 4, 4, 16, 32, 64
+    n, steps, val_pad = 4, 4, 64
     spec = lambda tree, lead=(): jax.tree.map(
         lambda x: _spec(one_chip, lead + x.shape, x.dtype), tree
     )
@@ -109,4 +120,4 @@ def test_cohort_round_eval_fits_one_chip(one_chip):
     compiled = fns.cohort_round_eval.lower(*args).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     total = _nbytes(args) + temp
-    assert total < 15 * GiB, f"args + temp = {total / GiB:.2f} GiB"
+    assert total < limit_gib * GiB, f"args + temp = {total / GiB:.2f} GiB"

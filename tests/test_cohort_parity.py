@@ -1,7 +1,8 @@
 """Batched cohort engine == sequential per-device loop, and the
 configurator's vector-rate interface.
 
-The batched engine (``cohort_round`` = vmap of ``local_round``) must be a
+The batched engine (``cohort_round``: ``local_round`` over a leading device
+axis, the devices in turn) must be a
 pure execution-strategy change: for identical seeds both modes consume the
 same PRNG streams and must produce numerically matching per-device PEFT
 trees, round metrics, PTLS importances, and accuracies.  Exercised through
@@ -23,12 +24,14 @@ _FED = FederatedConfig(num_devices=6, devices_per_round=4, local_steps=2, batch_
 _TRAIN = TrainConfig(learning_rate=5e-3, total_steps=100, warmup_steps=2)
 
 
-def _runner(mode, *, method="droppeft", stld_mode="cond", seed=3):
+def _runner(mode, *, method="droppeft", stld_mode="cond", seed=3, stld_enabled=True):
     return api.build(
         method,
         cfg=_CFG,
         peft_cfg=PEFTConfig(method="lora", lora_rank=2),
-        stld_cfg=STLDConfig(mode=stld_mode, mean_rate=0.5, gather_bucket=1),
+        stld_cfg=STLDConfig(
+            mode=stld_mode, mean_rate=0.5, gather_bucket=1, enabled=stld_enabled
+        ),
         fed_cfg=_FED,
         train_cfg=_TRAIN,
         seed=seed,
@@ -121,19 +124,35 @@ def test_configurator_vector_rate_interface():
     assert cfgor.best_rate() in grid
 
 
-def test_cond_mode_gates_cond_alone_select_in_cohort():
-    """Cond-mode STLD: the un-vmapped local round keeps one real ``cond`` per
-    layer, so a dropped layer skips its compute there; the vmapped cohort
-    programs gate with a select, which keeps the frozen base weights off the
-    cohort axis (a vmapped cond would copy them once per device)."""
+def _cond_depths(jaxpr, lengths=()):
+    """The lengths of the scans around every ``cond`` in ``jaxpr``."""
+    from jax.extend import core as jex_core
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            out.append(lengths)
+        inner = lengths + (eqn.params["length"],) if eqn.primitive.name == "scan" else lengths
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jex_core.Jaxpr):
+                    out += _cond_depths(sub, inner)
+    return out
+
+
+def test_cond_mode_cohort_skips_dropped_layers():
+    """Cond-mode STLD: the cohort programs train their devices in turn, so
+    every gate stays a real ``cond`` (``stablehlo.case``) inside the layer
+    loop, as many as the un-vmapped local round has, and a dropped layer
+    skips its compute (a vmapped cond would be a select that runs it).
+    With STLD disabled the layers are ungated and nothing lowers to a
+    case."""
     import jax.numpy as jnp
 
     from repro.optim import adamw_init
 
-    runner = _runner("batched")
-    client = runner.ctx.engine.client
-    base = runner.ctx.engine.base_params
-    peft = runner.state.global_peft
     n, s = 2, _FED.local_steps
     shape = (s, _FED.batch_size, 8)
     batch = {
@@ -141,16 +160,35 @@ def test_cond_mode_gates_cond_alone_select_in_cohort():
         "targets": jnp.zeros(shape, jnp.int32),
         "mask": jnp.ones(shape, jnp.float32),
     }
-    local = client.local_round.lower(
-        base, peft, adamw_init(peft), batch, jnp.float32(0.5),
-        jax.random.PRNGKey(0), jnp.int32(0),
-    ).as_text()
-    assert local.count("stablehlo.case") > 0
     stack = lambda t: jax.tree.map(lambda x: jnp.stack([x] * n), t)
     val = (jnp.zeros((n, 4, 8), jnp.int32), jnp.zeros((n, 4), jnp.int32), jnp.ones((n, 4)))
-    cohort = client.cohort_round_eval.lower(
-        base, stack(peft), stack(batch), jnp.full((n,), 0.5),
-        jax.random.split(jax.random.PRNGKey(0), n), jnp.zeros((n,), jnp.int32),
-        *val, runner.ctx.num_classes,
-    ).as_text()
-    assert cohort.count("stablehlo.case") == 0
+    for enabled in (True, False):
+        runner = _runner("batched", stld_enabled=enabled)
+        client = runner.ctx.engine.client
+        base = runner.ctx.engine.base_params
+        peft = runner.state.global_peft
+        local = client.local_round.lower(
+            base, peft, adamw_init(peft), batch, jnp.float32(0.5),
+            jax.random.PRNGKey(0), jnp.int32(0),
+        ).as_text()
+        args = (
+            base, stack(peft), stack(batch), jnp.full((n,), 0.5),
+            jax.random.split(jax.random.PRNGKey(0), n), jnp.zeros((n,), jnp.int32),
+        )
+        programs = {
+            "cohort_round_eval": (
+                client.cohort_round_eval, args + val + (runner.ctx.num_classes,)
+            ),
+            "cohort_round": (client.cohort_round, args),
+        }
+        for name, (fn, fn_args) in programs.items():
+            cases = fn.lower(*fn_args).as_text().count("stablehlo.case")
+            depths = _cond_depths(jax.make_jaxpr(fn)(*fn_args).jaxpr)
+            if not enabled:
+                assert cases == 0 and depths == [], name
+                continue
+            assert cases == local.count("stablehlo.case") > 0, name
+            # each cond sits in the layer scan, inside the scan over the
+            # cohort's devices
+            assert depths, name
+            assert all(d[0] == n and _CFG.num_layers in d[1:] for d in depths), (name, depths)

@@ -127,3 +127,66 @@ def test_gather_grads_zero_for_dropped_layers(key):
     for l in (1, 3):
         g_l = jax.tree.leaves(stacking.layer_view(g, l))
         assert any(float(jnp.abs(x).max()) > 0.0 for x in g_l)
+
+
+def test_gated_remat_scan_saves_no_copy_of_the_weights(key):
+    """The gradient through a gated, rematerialized layer scan saves each
+    layer's input, not its weights: the checkpoint wraps the ``cond`` with
+    the block.  A ``cond`` around a checkpointed block would save the
+    checkpoint's inputs, each layer's float32 weights among them, and the
+    scan would stack them into a copy of the whole stack."""
+    from repro.configs import PEFTConfig
+    from repro.core import peft as peft_lib
+    from repro.models.transformer import stack_apply
+
+    cfg = get_config("qwen3-1.7b", smoke=True).replace(
+        num_layers=8, d_model=256, d_ff=1024, num_heads=4, num_kv_heads=2, head_dim=64,
+        dtype="bfloat16",
+    )
+    layers = init_params(key, cfg)["layers"]
+    peft = peft_lib.init_peft(key, cfg, PEFTConfig(method="lora", lora_rank=4))
+    h = jax.random.normal(key, (2, 16, cfg.d_model), jnp.bfloat16)
+    drops = jnp.arange(cfg.num_layers) % 2 == 1
+
+    def loss(peft):
+        out, _, _ = stack_apply(
+            layers, cfg, h, positions=jnp.arange(16), drops=drops, peft=peft,
+            lora_scale=2.0, stack_mode="scan", remat=True,
+        )
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    temp = jax.jit(jax.grad(loss)).lower(peft).compile().memory_analysis().temp_size_in_bytes
+    weights = sum(x.nbytes for x in jax.tree.leaves(layers))
+    assert temp < weights, (temp, weights)
+
+
+@pytest.mark.parametrize("stack_mode", ["scan", "unroll"])
+def test_remat_gate_matches_the_plain_gate(key, stack_mode):
+    """Rematerialized as one unit, the gated stack gives the plain gated
+    stack's loss and adapter gradients; dropped layers get none."""
+    from repro.configs import PEFTConfig
+    from repro.core import peft as peft_lib
+    from repro.models import stacking
+
+    cfg = get_config("qwen3-1.7b", smoke=True).replace(num_layers=4, dtype="float32")
+    params = init_params(key, cfg)
+    peft = peft_lib.init_peft(key, cfg, PEFTConfig(method="lora", lora_rank=2))
+    peft = jax.tree.map(lambda x: x + 0.01, peft)  # every factor off zero
+    batch = {"tokens": jax.random.randint(key, (2, 8), 0, cfg.vocab_size)}
+    drops = jnp.array([False, True, False, True])
+
+    def loss(pf, remat):
+        lo, _, _ = model_apply(
+            params, cfg, batch, peft=pf, drops=drops, stack_mode=stack_mode, remat=remat
+        )
+        return jnp.mean(lo**2)
+
+    (l0, g0), (l1, g1) = (jax.value_and_grad(loss)(peft, r) for r in (False, True))
+    np.testing.assert_allclose(l1, l0, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    for l in (1, 3):
+        assert all(float(jnp.abs(x).max()) == 0.0 for x in jax.tree.leaves(stacking.layer_view(g1, l)))
+    with pytest.raises(ValueError, match="frozen"):
+        jax.grad(lambda p: model_apply(
+            p, cfg, batch, drops=drops, stack_mode=stack_mode, remat=True)[0].sum())(params)

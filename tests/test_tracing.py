@@ -74,17 +74,19 @@ def test_each_round_span_holds_its_phases_in_order(traced):
 
 def test_kept_layers_within_the_bodies_run(traced):
     runner, _ = traced
-    bodies = runner.ctx.engine.layer_bodies_per_step(0.5)
-    # the batched cohort gates with a select: every layer body runs
-    assert bodies == _CFG.num_layers
-    for row in runner.state.history:
-        assert 0 < row["active"] * _CFG.num_layers <= bodies
+    # the batched cohort gates with real conds: a step runs only the
+    # layers it keeps, which the rounds' active share counts
+    assert runner.ctx.engine.layer_bodies_per_step(0.5) is None
+    active = [row["active"] for row in runner.state.history]
+    for a in active:
+        assert 0 < a * _CFG.num_layers <= _CFG.num_layers
+    assert sum(active) < len(active)
 
 
 @pytest.mark.parametrize(
     "mode,cohort_mode,rate,bodies",
     [
-        ("cond", "batched", 0.0, _CFG.num_layers),
+        ("cond", "batched", 0.0, None),  # the conds run only the kept layers
         ("gather", "batched", 0.5, 2),  # round(4 x 0.5), bucket 1
         ("cond", "sequential", 0.5, None),  # the cond runs only the kept layers
     ],
