@@ -124,7 +124,9 @@ def _build_context(
                 f"{fed_cfg.num_devices} devices"
             )
     key, k1, k2 = jax.random.split(key, 3)
-    base_params = init_params(k1, cfg)
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg), k1)
+    with obs.span("init", bytes=sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))):
+        base_params = jax.block_until_ready(init_params(k1, cfg))
     global_peft = peft_lib.init_peft(k2, cfg, peft_cfg)
     ctx = ExperimentContext(
         cfg=cfg,

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import stld
 from repro.models import stacking
-from repro.models.layers import init_layer, init_layer_cache, layer_apply
+from repro.models.layers import init_layer, init_layer_cache, layer_apply, layer_kind
 from repro.nn.initializers import normal_init
 from repro.nn.norms import apply_layernorm, apply_rmsnorm, init_layernorm, init_rmsnorm
 
@@ -77,15 +77,27 @@ def init_lm(key, cfg, layout: str = "auto"):
     """
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    layers = [init_layer(layer_keys[l], cfg, l) for l in range(cfg.num_layers)]
     params = {
         "embed": normal_init(k_emb, (cfg.vocab_size, cfg.d_model)),
-        "layers": stacking.maybe_stack(layers, layout),
+        "layers": _init_layers(layer_keys, cfg, layout),
         "final_norm": _norm_init(cfg, cfg.d_model),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = normal_init(k_head, (cfg.d_model, cfg.vocab_size))
     return params
+
+
+def _init_layers(layer_keys, cfg, layout: str):
+    """The layer stack.  A homogeneous stack in a stacked layout is drawn
+    stacked, each leaf at once over the layer keys, and its values are the
+    per-layer draw's to the bit.  Drawing layer by layer and then stacking
+    holds both copies at once, which at h2o-danube-1.8b's widths does not
+    fit one 16 GB chip."""
+    kinds = {(layer_kind(cfg, l), cfg.is_moe_layer(l)) for l in range(cfg.num_layers)}
+    if layout != "list" and len(kinds) == 1:
+        return jax.vmap(lambda k: init_layer(k, cfg, 0))(layer_keys)
+    layers = [init_layer(layer_keys[l], cfg, l) for l in range(cfg.num_layers)]
+    return stacking.maybe_stack(layers, layout)
 
 
 def init_caches(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, layout: str = "list"):

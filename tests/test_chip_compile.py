@@ -2,10 +2,10 @@
 fails here, without a chip.
 
 Nothing runs: these tests lower and compile the main path's kernel and its
-largest training program at qwen3-1.7b's published widths, and read what the
-compiler reports.  The topology is described inside a module fixture, never
-at import, so that under pytest-xdist only the worker given this file loads
-the TPU compiler.
+largest training program at the published widths of the benchmark's
+configurations, and read what the compiler reports.  The topology is
+described inside a module fixture, never at import, so that under
+pytest-xdist only the worker given this file loads the TPU compiler.
 """
 import os
 
@@ -72,21 +72,23 @@ def test_segmented_lora_compiles_at_qwen3_width(one_chip, n):
 
 
 @pytest.mark.parametrize(
-    "batch,seq,limit_gib",
+    "arch,batch,seq,limit_gib",
     [
-        (16, 32, 15.0),
+        pytest.param("qwen3-1.7b", 16, 32, 15.0, id="b16s32"),
         # the round cell's shape: the cohort in turn compiles at 11.40 GiB
         # there, where the select-gated program took 14.71; a layer weight
         # copy per cohort member or per layer would pass 12
-        (8, 128, 12.0),
+        pytest.param("qwen3-1.7b", 8, 128, 12.0, id="cell"),
+        # the danube round cell's shape, head_dim 80 and an untied 32k head:
+        # 11.22 GiB (arguments 6.85, temp 4.38)
+        pytest.param("h2o-danube-1.8b", 16, 128, 12.0, id="danube-cell"),
     ],
-    ids=["b16s32", "cell"],
 )
-def test_cohort_round_eval_fits_one_chip(one_chip, batch, seq, limit_gib):
+def test_cohort_round_eval_fits_one_chip(one_chip, arch, batch, seq, limit_gib):
     """The full-width cohort-4 train+eval program in cond-mode STLD: the
     frozen base has one copy whatever the cohort, so arguments plus the
     compiler's temp space stay under the limit of the chip's 16 GB."""
-    cfg = get_config("qwen3-1.7b")
+    cfg = get_config(arch)
     pcfg = PEFTConfig()
     n, steps, val_pad = 4, 4, 64
     spec = lambda tree, lead=(): jax.tree.map(

@@ -90,6 +90,23 @@ def test_layout_round_trip_all_families(arch, key):
                 stacking.stack_params(l)
 
 
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "h2o-danube-1.8b"])
+def test_homogeneous_stack_is_drawn_stacked(arch, key, monkeypatch):
+    """A homogeneous stack is drawn stacked, never built layer by layer and
+    then stacked (which holds both copies at once), and its values are the
+    per-layer draw's to the bit."""
+    cfg = get_config(arch, smoke=True)
+    listed = init_params(key, cfg, layout="list")["layers"]
+
+    def refuse(layers):
+        raise AssertionError("the init stacked a per-layer list")
+
+    monkeypatch.setattr(stacking, "stack_params", refuse)
+    drawn = init_params(key, cfg)["layers"]
+    assert jax.tree.structure(drawn) == jax.tree.structure(listed[0])
+    _tree_equal(drawn, jax.tree.map(lambda *xs: np.stack(xs), *listed))
+
+
 @pytest.mark.parametrize("method", ["lora", "adapter", "bitfit"])
 def test_peft_layout_round_trip(method, key):
     pcfg = PEFTConfig(method=method, lora_rank=2, adapter_dim=4)

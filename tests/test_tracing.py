@@ -57,6 +57,17 @@ def traced(tmp_path_factory):
     return runner, spans
 
 
+def test_init_span_carries_the_parameter_bytes(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        runner = _runner(0.5)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    (init,) = [dict(e.stats) for plane in ProfileData.from_file(str(path)).planes
+               if plane.name.startswith("/host")
+               for line in plane.lines for e in line.events if e.name == "repro.init"]
+    base = jax.tree.leaves(runner.ctx.engine.base_params)
+    assert init == {"bytes": sum(x.nbytes for x in base)}
+
+
 def test_each_round_span_holds_its_phases_in_order(traced):
     _, spans = traced
     rounds = [s for s in spans if s[0] == "repro.round"]
