@@ -16,6 +16,7 @@ real fine-tuning while giving a learnable, partitionable labelled dataset.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import ClassVar, Optional
 
 import numpy as np
 
@@ -28,6 +29,10 @@ class SyntheticTask:
     num_classes: int
     tokens: np.ndarray  # (N, seq_len) int32; last position is the query token
     labels: np.ndarray  # (N,) int32 class ids
+
+    # the loss falls on the last ``loss_tail`` positions only (``lm_batch``
+    # masks the rest), so training computes head and loss there alone
+    loss_tail: ClassVar[Optional[int]] = 1
 
     @property
     def label_tokens(self) -> np.ndarray:

@@ -69,6 +69,7 @@ def make_client_fns(
     *,
     stack_mode: str = "unroll",
     donate: Optional[bool] = None,
+    loss_tail: Optional[int] = None,
 ) -> ClientFns:
     """Build the jit'd per-round client programs.
 
@@ -97,7 +98,17 @@ def make_client_fns(
     inside the one program, since under ``vmap`` a ``cond`` whose predicate
     differs per device becomes a select that runs every layer.  The fused
     validation is vmapped over the trained trees.
+
+    ``loss_tail`` (static; ``None`` keeps every position) states that the
+    training loss falls on the last ``loss_tail`` positions only: the final
+    norm, the head and the loss then run there alone.  Rows are independent
+    through all three and a masked position adds zero to the loss, the
+    accuracy and every gradient, so the step computes the same numbers; the
+    caller guarantees that ``mask`` is zero before the tail.  Validation
+    reads the last position and takes no tail.
     """
+    if loss_tail is not None and loss_tail < 1:
+        raise ValueError(f"loss_tail must be at least 1, got {loss_tail}")
     if donate is None:
         donate = jax.default_backend() != "cpu"
     lora_sc = peft_lib.lora_scale(peft_cfg) if peft_cfg.method == "lora" else 1.0
@@ -117,8 +128,11 @@ def make_client_fns(
             stack_mode="gather" if active_idx is not None else stack_mode,
             active_idx=active_idx,
             remat=True,
+            tail=loss_tail,
         )
         logits = _logits_for_tokens(cfg, logits, tokens)
+        if loss_tail is not None:
+            targets, mask = targets[:, -loss_tail:], mask[:, -loss_tail:]
         loss, metrics = softmax_xent(logits, targets, mask)
         loss = loss + cfg.router_aux_coef * aux
         return loss, metrics

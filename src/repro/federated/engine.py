@@ -78,8 +78,12 @@ class CohortEngine:
         self.stld_enabled = stld_enabled
 
         self.stack_mode = default_stack_mode(cfg)
+        # the positions the task's loss can fall on: the training step runs
+        # head and loss there alone (a task without the attribute keeps all)
+        self.loss_tail = getattr(task, "loss_tail", None)
         self.client = make_client_fns(
-            cfg, peft_cfg, stld_cfg, train_cfg, stack_mode=self.stack_mode
+            cfg, peft_cfg, stld_cfg, train_cfg,
+            stack_mode=self.stack_mode, loss_tail=self.loss_tail,
         )
         self.local_round, self.evaluate = self.client.local_round, self.client.evaluate
         # server aggregation is pure tree math: jit it so a round's
@@ -106,7 +110,8 @@ class CohortEngine:
                 **{**self.peft_cfg.__dict__, "lora_rank": r}
             )
             self._het_fns[r] = make_client_fns(
-                self.cfg, pc, self.stld_cfg, self.train_cfg, stack_mode=self.stack_mode
+                self.cfg, pc, self.stld_cfg, self.train_cfg,
+                stack_mode=self.stack_mode, loss_tail=self.loss_tail,
             )
 
     # ------------------------------------------------------------- execution
@@ -159,9 +164,15 @@ class CohortEngine:
     def _stacked_train_batches(self, dev: int):
         fed = self.fed_cfg
         batches = list(self.devices[dev].train_batches(fed.batch_size, fed.local_steps))
-        return {
+        stacked = {
             k: np.stack([b[k] for b in batches]) for k in ("tokens", "targets", "mask")
         }
+        if self.loss_tail is not None and np.any(stacked["mask"][..., : -self.loss_tail]):
+            raise ValueError(
+                f"device {dev}: a loss mask is nonzero before the last "
+                f"{self.loss_tail} positions, which the task's loss_tail excludes"
+            )
+        return stacked
 
     def _padded_val_batch(self, dev: int):
         """Val batch padded to the cohort-wide size with a validity mask.
@@ -207,6 +218,15 @@ class CohortEngine:
             return na
         return None if self.stld_cfg.enabled else self.cfg.num_layers
 
+    def head_positions_per_step(self) -> int:
+        """Positions one client step runs the head and the loss at: ``batch
+        x loss_tail``, or every position of the batch without a tail."""
+        fed = self.fed_cfg
+        if self.loss_tail is not None:
+            return fed.batch_size * self.loss_tail
+        seq = self.task.seq_len + (self.cfg.frontend_seq if self.cfg.modality == "vision" else 0)
+        return fed.batch_size * seq
+
     def _run_cohort_batched(
         self, cohort, rates, start_pefts, keys, gsteps, num_classes, adaopt_depth
     ):
@@ -214,8 +234,9 @@ class CohortEngine:
 
         Host spans, inside the round's own: ``round.stage`` (stacking
         and host-to-device copies), ``round.dispatch`` (enqueueing the
-        program) and ``round.pull`` (the unstack and the one ``device_get``
-        that waits for it)."""
+        program; arg ``head_rows``, :meth:`head_positions_per_step`) and
+        ``round.pull`` (the unstack and the one ``device_get`` that waits
+        for it)."""
         n = len(cohort)
         adaopt = adaopt_depth < self.cfg.num_layers
         num_active = self._static_active_counts(rates)
@@ -240,7 +261,7 @@ class CohortEngine:
                     jnp.asarray(np.stack([v[k] for v in val_list]))
                     for k in ("tokens", "labels", "valid")
                 )
-            with obs.span("round.dispatch"):
+            with obs.span("round.dispatch", head_rows=self.head_positions_per_step()):
                 if adaopt:
                     # progressive depth discards deep-layer updates before
                     # eval, so train and eval cannot be fused: train,

@@ -107,8 +107,11 @@ def decode(
     lora_scale: float = 1.0,
     stack_mode: str = "unroll",
     remat: bool = False,
+    tail: Optional[int] = None,
 ):
-    """tokens: (B, S_dec).  Returns (logits, aux, new_caches)."""
+    """tokens: (B, S_dec).  ``tail`` runs the final norm and the output
+    projection on the last ``tail`` positions only.  Returns (logits, aux,
+    new_caches)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     dec = params["decoder"]
     h = dec["embed"][tokens].astype(compute_dtype)
@@ -130,6 +133,8 @@ def decode(
         stack_mode=stack_mode,
         remat=remat,
     )
+    if tail is not None:
+        h = h[:, -tail:]
     h = _norm_apply(cfg, dec["final_norm"], h)
     logits = h @ dec["embed"].T.astype(compute_dtype)  # whisper ties output proj
     return logits, aux, new_caches

@@ -50,6 +50,7 @@ def model_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
+    tail=None,
 ):
     if cfg.is_encoder_decoder:
         if enc_kvs is None:
@@ -73,6 +74,7 @@ def model_apply(
             lora_scale=lora_scale,
             stack_mode=stack_mode if stack_mode in ("unroll", "scan") else "unroll",
             remat=remat,
+            tail=tail,
         )
     prefix = batch.get("patches") if cfg.modality == "vision" else None
     return transformer.lm_apply(
@@ -88,6 +90,7 @@ def model_apply(
         stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
+        tail=tail,
     )
 
 

@@ -332,11 +332,14 @@ def lm_apply(
     stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
+    tail: Optional[int] = None,
 ):
     """Decoder-only LM forward.
 
     tokens: (B, S) int32.  ``prefix_embeds`` (B, P, d) is prepended (VLM stub
-    frontend).  Returns (logits, aux, new_caches).
+    frontend).  ``tail`` runs the final norm and the head on the last
+    ``tail`` positions only, so logits are (B, tail, V).  Returns (logits,
+    aux, new_caches).
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     h = params["embed"][tokens].astype(compute_dtype)
@@ -359,6 +362,8 @@ def lm_apply(
         active_idx=active_idx,
         remat=remat,
     )
+    if tail is not None:
+        h = h[:, -tail:]
     h = _norm_apply(cfg, params["final_norm"], h)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = h @ head.astype(compute_dtype)

@@ -135,7 +135,8 @@ class FederatedSimulator:
         self.device_peft: Dict[int, list] = {}
         stack_mode = default_stack_mode(cfg)
         self.client = make_client_fns(
-            cfg, peft_cfg, stld_cfg, train_cfg, stack_mode=stack_mode
+            cfg, peft_cfg, stld_cfg, train_cfg, stack_mode=stack_mode,
+            loss_tail=self.task.loss_tail,
         )
         self.local_round, self.evaluate = self.client.local_round, self.client.evaluate
         # server aggregation is pure tree math: jit it so a round's
@@ -178,7 +179,8 @@ class FederatedSimulator:
             for r in set(self.device_rank):
                 pc = peft_cfg.__class__(**{**peft_cfg.__dict__, "lora_rank": r})
                 self._het_fns[r] = make_client_fns(
-                    cfg, pc, stld_cfg, train_cfg, stack_mode=stack_mode
+                    cfg, pc, stld_cfg, train_cfg, stack_mode=stack_mode,
+                    loss_tail=self.task.loss_tail,
                 )
 
     # ------------------------------------------------------------------ run

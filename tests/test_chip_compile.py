@@ -8,6 +8,7 @@ described inside a module fixture, never at import, so that under
 pytest-xdist only the worker given this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import PEFTConfig, STLDConfig, TrainConfig, get_config
 from repro.core import peft as peft_lib
+from repro.data.synthetic import SyntheticTask
 from repro.federated.client import make_client_fns
 from repro.kernels.segmented_lora import segmented_lora_pallas
 from repro.models.registry import init_params
@@ -38,6 +40,22 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def cohort_programs(one_chip):
+    """``compile(arch, batch, seq)``: the full-width cohort-4 train+eval
+    program in cond-mode STLD, built as the engine builds it for the
+    synthetic task (head and loss at its ``loss_tail``), compiled once per
+    shape; returns ``(compiled, argument bytes)``."""
+    cache = {}
+
+    def compile_(arch, batch, seq):
+        if (arch, batch, seq) not in cache:
+            cache[arch, batch, seq] = _compile_cohort_round_eval(one_chip, arch, batch, seq)
+        return cache[arch, batch, seq]
+
+    return compile_
 
 
 def _spec(one_chip, shape, dtype):
@@ -75,19 +93,44 @@ def test_segmented_lora_compiles_at_qwen3_width(one_chip, n):
     "arch,batch,seq,limit_gib",
     [
         pytest.param("qwen3-1.7b", 16, 32, 15.0, id="b16s32"),
-        # the round cell's shape: the cohort in turn compiles at 11.40 GiB
-        # there, where the select-gated program took 14.71; a layer weight
-        # copy per cohort member or per layer would pass 12
+        # the round cell's shape: the cohort in turn compiles at 10.53 GiB
+        # there with head and loss at the last position (11.40 over every
+        # position), where the select-gated program took 14.71; a layer
+        # weight copy per cohort member or per layer would pass 12
         pytest.param("qwen3-1.7b", 8, 128, 12.0, id="cell"),
         # the danube round cell's shape, head_dim 80 and an untied 32k head:
         # 11.22 GiB (arguments 6.85, temp 4.38)
         pytest.param("h2o-danube-1.8b", 16, 128, 12.0, id="danube-cell"),
     ],
 )
-def test_cohort_round_eval_fits_one_chip(one_chip, arch, batch, seq, limit_gib):
+def test_cohort_round_eval_fits_one_chip(cohort_programs, arch, batch, seq, limit_gib):
     """The full-width cohort-4 train+eval program in cond-mode STLD: the
     frozen base has one copy whatever the cohort, so arguments plus the
     compiler's temp space stay under the limit of the chip's 16 GB."""
+    compiled, arg_bytes = cohort_programs(arch, batch, seq)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    total = arg_bytes + temp
+    assert total < limit_gib * GiB, f"args + temp = {total / GiB:.2f} GiB"
+
+
+@pytest.mark.parametrize(
+    "arch,batch,seq",
+    [
+        pytest.param("qwen3-1.7b", 8, 128, id="cell"),
+        pytest.param("h2o-danube-1.8b", 16, 128, id="danube-cell"),
+    ],
+)
+def test_training_head_runs_at_the_loss_tail_only(cohort_programs, arch, batch, seq):
+    """With the synthetic task's tail of 1 no op of the round cells' program
+    makes a ``[batch, seq, vocab]`` result: the training head and its loss
+    run at the last position."""
+    compiled, _ = cohort_programs(arch, batch, seq)
+    vocab = get_config(arch).vocab_size
+    full = re.findall(rf"\w+\[(?:\d+,)*{batch},{seq},{vocab}\]", compiled.as_text())
+    assert not full, f"head products over every position: {sorted(set(full))}"
+
+
+def _compile_cohort_round_eval(one_chip, arch, batch, seq):
     cfg = get_config(arch)
     pcfg = PEFTConfig()
     n, steps, val_pad = 4, 4, 64
@@ -117,9 +160,7 @@ def test_cohort_round_eval_fits_one_chip(one_chip, arch, batch, seq, limit_gib):
         i32(4),
     )
     fns = make_client_fns(
-        cfg, pcfg, STLDConfig(mode="cond"), TrainConfig(), stack_mode="scan", donate=True
+        cfg, pcfg, STLDConfig(mode="cond"), TrainConfig(), stack_mode="scan", donate=True,
+        loss_tail=SyntheticTask.loss_tail,
     )
-    compiled = fns.cohort_round_eval.lower(*args).compile()
-    temp = compiled.memory_analysis().temp_size_in_bytes
-    total = _nbytes(args) + temp
-    assert total < limit_gib * GiB, f"args + temp = {total / GiB:.2f} GiB"
+    return fns.cohort_round_eval.lower(*args).compile(), _nbytes(args)

@@ -1,7 +1,9 @@
 """The program's own tracing (``repro.obs``): the host spans of a sync round,
-the layer bodies a client step runs in each gating mode, and the named scopes
-of the client programs, which add metadata and nothing else."""
+the layer bodies a client step runs in each gating mode, the rows it runs the
+head at, and the named scopes of the client programs, which add metadata and
+nothing else."""
 import contextlib
+import dataclasses
 import re
 
 import jax
@@ -10,6 +12,8 @@ from jax.profiler import ProfileData
 
 from repro import api
 from repro.configs import FederatedConfig, PEFTConfig, STLDConfig, TrainConfig, get_config
+from repro.data import make_task
+from repro.data.synthetic import SyntheticTask
 from repro.federated.client import make_client_fns
 
 _CFG = get_config("qwen3-1.7b", smoke=True).replace(
@@ -22,16 +26,17 @@ _PEFT = PEFTConfig(method="lora", lora_rank=2)
 PHASES = ["configure", "stage", "dispatch", "pull", "aggregate", "report"]
 
 
-def _runner(rate, seed=3, mode="cond", cohort_mode="batched"):
+def _runner(rate, seed=3, mode="cond", cohort_mode="batched", fed_cfg=_FED, task=None):
     return api.build(
         "droppeft",
         cfg=_CFG,
         peft_cfg=_PEFT,
         stld_cfg=STLDConfig(mode=mode, mean_rate=rate, gather_bucket=1),
         fixed_rate=rate,
-        fed_cfg=_FED,
+        fed_cfg=fed_cfg,
         train_cfg=_TRAIN,
         schedule="sync",
+        task=task,
         seed=seed,
         cohort_mode=cohort_mode,
     )
@@ -75,8 +80,12 @@ def test_each_round_span_holds_its_phases_in_order(traced):
     for _, r0, r1, _ in rounds:
         inside = [s for s in spans if r0 <= s[1] and s[2] <= r1 and s[0] != "repro.round"]
         assert [s[0] for s in inside] == [f"repro.round.{p}" for p in PHASES]
-        # the phases take their round from the span around them
-        assert all(s[3] == {} for s in inside)
+        # the phases take their round from the span around them; the
+        # dispatch carries the head's rows per client step (the synthetic
+        # task's tail of 1 on each of the batch's rows)
+        args = {s[0]: s[3] for s in inside}
+        assert args.pop("repro.round.dispatch") == {"head_rows": _FED.batch_size}
+        assert all(a == {} for a in args.values())
     # the evaluation that ends the run call follows the rounds
     (evaluate,) = [s for s in spans if s[0] == "repro.evaluate"]
     assert evaluate[1] >= rounds[-1][2]
@@ -105,6 +114,26 @@ def test_kept_layers_within_the_bodies_run(traced):
 def test_layer_bodies_per_step_follows_the_gating_mode(mode, cohort_mode, rate, bodies):
     engine = _runner(rate, mode=mode, cohort_mode=cohort_mode).ctx.engine
     assert engine.layer_bodies_per_step(rate) == bodies
+
+
+class _EveryPosition(SyntheticTask):
+    """The synthetic task stated with no loss tail."""
+
+    loss_tail = None
+
+
+@pytest.mark.parametrize("tail,rows", [(1, 8), (None, 8 * 128)], ids=["tail", "every"])
+def test_head_positions_per_step_at_the_round_cell_shape(tail, rows):
+    """8 rows of 128 tokens per client step, as in the qwen round cell: the
+    head runs at the synthetic task's last position, or at every position
+    of a task that states no tail."""
+    task = make_task(num_examples=64, vocab_size=_CFG.vocab_size, seq_len=128, seed=0)
+    if tail is None:
+        task = _EveryPosition(**{f.name: getattr(task, f.name) for f in dataclasses.fields(task)})
+    fed = FederatedConfig(num_devices=2, devices_per_round=2, local_steps=1, batch_size=8)
+    engine = _runner(0.5, fed_cfg=fed, task=task).ctx.engine
+    assert engine.loss_tail == tail
+    assert engine.head_positions_per_step() == rows
 
 
 _METADATA = re.compile(r",? metadata=\{[^}]*\}")
