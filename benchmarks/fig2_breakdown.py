@@ -27,7 +27,7 @@ def run(quick: bool = False):
 
     @jax.jit
     def fwd(p, pf, drops):
-        logits, aux, _ = model_apply(p, cfg, {"tokens": batch}, peft=pf, drops=drops, stack_mode="scan")
+        logits, aux, _ = model_apply(p, cfg, {"tokens": batch}, peft=pf, drops=drops)
         loss, _ = softmax_xent(logits[:, :-1], batch[:, 1:])
         return loss
 

@@ -74,7 +74,7 @@ def run(quick: bool = False):
     cfg = sim_model_cfg()
     pcfg = PEFTConfig(method="lora", lora_rank=4)
     scfg = STLDConfig(mode="cond", mean_rate=0.5)
-    fns = make_client_fns(cfg, pcfg, scfg, train_cfg(), stack_mode="scan", donate=False)
+    fns = make_client_fns(cfg, pcfg, scfg, train_cfg(), donate=False)
     key = jax.random.PRNGKey(0)
 
     layouts = {}

@@ -24,7 +24,6 @@ from benchmarks import (
     fig13_14_ablations,
     fig15_noniid,
     kernel_bench,
-    roofline,
     serve_bench,
     table1_overhead,
     table3_time_to_accuracy,
@@ -47,7 +46,6 @@ BENCHES = {
     "fig13_14": fig13_14_ablations.run,
     "fig15": fig15_noniid.run,
     "kernels": kernel_bench.run,
-    "roofline": roofline.run,
 }
 
 

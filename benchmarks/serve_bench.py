@@ -68,7 +68,7 @@ def run(quick: bool = False):
     cfg = sim_model_cfg()
     key = jax.random.PRNGKey(0)
     params = init_params(key, cfg)
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
     reg = _registry(cfg, key)
     max_len = _PROMPT + gen_len + 1
 

@@ -464,9 +464,7 @@ def _client_setup(num_layers, peft_method, lora_rank, stld_cfg):
 
     cfg = _smoke_cfg(num_layers)
     pcfg = PEFTConfig(method=peft_method, lora_rank=lora_rank, adapter_dim=4)
-    fns = make_client_fns(
-        cfg, pcfg, stld_cfg, _train_cfg(), stack_mode="scan", donate=False
-    )
+    fns = make_client_fns(cfg, pcfg, stld_cfg, _train_cfg(), donate=False)
     key = jax.random.PRNGKey(0)
     base = init_params(key, cfg)
     peft = peft_lib.init_peft(key, cfg, pcfg)
@@ -672,13 +670,13 @@ def decode_trace(*, where="serving/decode", num_tokens=4) -> ProgramTrace:
     cached = _trace_cache.get(key)
     if cached is None:
         from repro.launch.steps import make_serve_step
-        from repro.models.registry import default_stack_mode, init_params
+        from repro.models.registry import init_params
         from repro.models.transformer import init_caches
         from repro.serving.decode import generate
 
         cfg = _smoke_cfg(4)
         params = init_params(jax.random.PRNGKey(0), cfg)
-        serve = make_serve_step(cfg, stack_mode=default_stack_mode(cfg))
+        serve = make_serve_step(cfg)
         caches = init_caches(cfg, 2, 16, dtype=jnp.dtype(cfg.dtype))
         first = jnp.zeros((2, 1), dtype=jnp.int32)
         closed = jax.make_jaxpr(
@@ -722,7 +720,7 @@ def batched_decode_trace(
             )
         pool = AdapterPoolCache(registry, n_slots=2)
         peft = pool.pooled_peft(jnp.asarray([0, 1], jnp.int32))
-        serve = make_serve_step(cfg, stack_mode="scan")
+        serve = make_serve_step(cfg)
         caches = batched_caches(cfg, 2, 16, dtype=jnp.dtype(cfg.dtype))
         token = jnp.zeros((2, 1), dtype=jnp.int32)
         pos = jnp.zeros((2,), dtype=jnp.int32)

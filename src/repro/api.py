@@ -193,7 +193,6 @@ def serve(
     batch: int = 4,
     max_len: int = 256,
     n_slots: Optional[int] = None,
-    stack_mode: str = "scan",
     cache_dtype: str = "bfloat16",
     seed: int = 0,
 ):
@@ -230,7 +229,7 @@ def serve(
     pool = AdapterPoolCache(
         registry, n_slots=n_slots if n_slots is not None else max(batch, len(registry))
     )
-    serve_step = make_serve_step(cfg, stack_mode=stack_mode)
+    serve_step = make_serve_step(cfg)
     return ContinuousBatcher(
         serve_step,
         params,

@@ -2,8 +2,7 @@
 
 Every assigned architecture is described by a single :class:`ModelConfig`
 dataclass.  Configs are plain data — no jax imports — so they can be loaded
-by launchers before device initialisation (important for the dry-run, which
-must set XLA_FLAGS before jax is touched).
+by launchers before device initialisation.
 """
 from __future__ import annotations
 

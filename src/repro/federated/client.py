@@ -67,7 +67,6 @@ def make_client_fns(
     stld_cfg,
     train_cfg,
     *,
-    stack_mode: str = "unroll",
     donate: Optional[bool] = None,
     loss_tail: Optional[int] = None,
 ) -> ClientFns:
@@ -125,7 +124,6 @@ def make_client_fns(
             drops=drops,
             peft=peft_params,
             lora_scale=lora_sc,
-            stack_mode="gather" if active_idx is not None else stack_mode,
             active_idx=active_idx,
             remat=True,
             tail=loss_tail,
@@ -252,7 +250,6 @@ def make_client_fns(
             _model_batch(cfg, tokens),
             peft=peft_params,
             lora_scale=lora_sc,
-            stack_mode=stack_mode,
         )
         logits = _logits_for_tokens(cfg, logits, tokens)
         final = logits[:, -1].astype(jnp.float32)  # (B, V)

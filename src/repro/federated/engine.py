@@ -29,7 +29,10 @@ per param kind, leading ``(L, ...)`` layer axis — see
 :mod:`repro.models.stacking`) whenever the stack is homogeneous, so the
 cohort stack/unstack helpers and every client dispatch handle O(k) leaves
 instead of O(L·k); the per-layer list layout (hetlora, legacy callers)
-keeps working through the same code paths.
+keeps working through the same code paths.  How the layer stack runs
+follows from the model config alone (one layer loop,
+:func:`repro.models.transformer.stack_apply`); the engine chooses only how
+the cohort is dispatched.
 """
 from __future__ import annotations
 
@@ -45,7 +48,6 @@ from repro.core import stld as stld_lib
 from repro.federated import server as server_lib
 from repro.federated.client import make_client_fns
 from repro.models import stacking
-from repro.models.registry import default_stack_mode
 from repro.optim import adamw_init
 
 
@@ -77,13 +79,11 @@ class CohortEngine:
         self.cohort_mode = cohort_mode
         self.stld_enabled = stld_enabled
 
-        self.stack_mode = default_stack_mode(cfg)
         # the positions the task's loss can fall on: the training step runs
         # head and loss there alone (a task without the attribute keeps all)
         self.loss_tail = getattr(task, "loss_tail", None)
         self.client = make_client_fns(
-            cfg, peft_cfg, stld_cfg, train_cfg,
-            stack_mode=self.stack_mode, loss_tail=self.loss_tail,
+            cfg, peft_cfg, stld_cfg, train_cfg, loss_tail=self.loss_tail
         )
         self.local_round, self.evaluate = self.client.local_round, self.client.evaluate
         # server aggregation is pure tree math: jit it so a round's
@@ -110,8 +110,7 @@ class CohortEngine:
                 **{**self.peft_cfg.__dict__, "lora_rank": r}
             )
             self._het_fns[r] = make_client_fns(
-                self.cfg, pc, self.stld_cfg, self.train_cfg,
-                stack_mode=self.stack_mode, loss_tail=self.loss_tail,
+                self.cfg, pc, self.stld_cfg, self.train_cfg, loss_tail=self.loss_tail
             )
 
     # ------------------------------------------------------------- execution

@@ -1,8 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 ``impl='pallas'`` runs the kernels (interpret mode on CPU, native on TPU);
-``impl='xla'`` dispatches to the pure-jnp reference path — the default for
-dry-run lowering since Pallas does not lower to the XLA CPU backend.
+``impl='xla'`` dispatches to the pure-jnp reference path, since Pallas does
+not lower to the XLA CPU backend.
 
 The public functions resolve the backend question (``interpret`` =
 running-on-CPU) *outside* the traced region and pass the answer through a

@@ -1,1 +1,1 @@
-"""Launchers: production meshes, dry-run, end-to-end train/serve drivers."""
+"""Launchers: end-to-end train/serve drivers, step functions, input specs."""

@@ -1,8 +1,8 @@
 """ShapeDtypeStruct stand-ins + shardings for every (arch x shape x step).
 
 Nothing here allocates device memory: parameters, optimizer states, and KV
-caches are built with ``jax.eval_shape`` over the real init functions, so the
-dry-run lowers the exact production program.
+caches are built with ``jax.eval_shape`` over the real init functions, so a
+program lowered from them is the exact production program.
 """
 from __future__ import annotations
 

@@ -1,40 +1,11 @@
-"""Production meshes.
+"""Mesh axis conventions.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state — the dry-run must set
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before jax
-initialises devices.
-
-Target hardware: TPU v5e pods; single pod = 16x16 = 256 chips
-(data x model), multi-pod = 2 x 16 x 16 = 512 chips (pod x data x model).
 In the federated mapping (DESIGN.md §7) the ``pod``+``data`` axes carry the
 client cohort / per-client batch; ``model`` carries tensor/expert parallel.
 """
 from __future__ import annotations
 
-import jax
-
-# TPU v5e per-chip constants used by the roofline (benchmarks/roofline.py)
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # B/s
-ICI_BW_PER_LINK = 50e9         # B/s per link
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """1x1 mesh over the real local device (CPU smoke tests, examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
-
 
 def data_axes(mesh) -> tuple:
     """Mesh axes that carry the batch/cohort dimension."""
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-
-
-def model_axis_size(mesh) -> int:
-    return mesh.shape["model"]

@@ -112,12 +112,11 @@ def main():
             params["layers"], peft_tree, peft_lib.lora_scale(peft_cfg)))
         print("merged LoRA into base weights")
 
-    stack_mode = "unroll"
     max_len = args.prompt_len + args.gen_len
     if cfg.modality == "vision":
         max_len += cfg.frontend_seq  # cache also holds the patch prefix
-    prefill = jax.jit(make_prefill_step(cfg, stack_mode=stack_mode))
-    serve = jax.jit(make_serve_step(cfg, stack_mode=stack_mode))
+    prefill = jax.jit(make_prefill_step(cfg))
+    serve = jax.jit(make_serve_step(cfg))
 
     prompt = jax.random.randint(key, (args.batch, args.prompt_len), 0, cfg.vocab_size)
     batch = {"tokens": prompt}

@@ -1,5 +1,4 @@
-"""Production step functions (train / prefill / serve) used by the drivers
-and lowered by the multi-pod dry-run.
+"""Production step functions (train / prefill / serve) used by the drivers.
 
 ``stld`` argument selects the paper semantics:
   * ``off``    — plain federated PEFT (FedLoRA/FedAdapter baseline compute)
@@ -29,7 +28,6 @@ def make_train_step(
     stld_mode: str = "off",
     mean_rate: float = 0.5,
     distribution: str = "incremental",
-    stack_mode: str = "unroll",
     gather_bucket: int = 4,
     remat: bool = False,
     regather_specs=None,
@@ -61,11 +59,6 @@ def make_train_step(
             drops=drops,
             peft=peft_params,
             lora_scale=lora_sc,
-            stack_mode=(
-                ("gather_unroll" if stack_mode == "unroll" else "gather")
-                if active_idx is not None
-                else stack_mode
-            ),
             active_idx=active_idx,
             remat=remat,
         )
@@ -108,35 +101,27 @@ def make_train_step(
     return train_step
 
 
-def make_prefill_step(cfg, *, stack_mode: str = "unroll"):
+def make_prefill_step(cfg):
     """(params, batch, caches) -> (last_logits, caches).
 
     batch: {"tokens": (B, S) [, "patches" | "frames"]}.
     """
 
     def prefill_step(params, batch, caches):
-        kw = {}
         if cfg.is_encoder_decoder:
-            enc_out = encdec.encode(params, cfg, batch["frames"], stack_mode=stack_mode)
+            enc_out = encdec.encode(params, cfg, batch["frames"])
             enc_kvs = encdec.encoder_cross_kvs(params, cfg, enc_out)
             logits, _, caches = encdec.decode(
-                params,
-                cfg,
-                batch["tokens"],
-                enc_kvs,
-                caches=caches,
-                stack_mode=stack_mode,
+                params, cfg, batch["tokens"], enc_kvs, caches=caches
             )
             return logits[:, -1], caches, enc_kvs
-        logits, _, caches = model_apply(
-            params, cfg, batch, caches=caches, stack_mode=stack_mode, **kw
-        )
+        logits, _, caches = model_apply(params, cfg, batch, caches=caches)
         return logits[:, -1], caches
 
     return prefill_step
 
 
-def make_serve_step(cfg, *, stack_mode: str = "unroll"):
+def make_serve_step(cfg):
     """Single-token decode against a KV cache.
 
     (params, token (B,1), pos (), caches [, enc_kvs]) ->
@@ -163,7 +148,6 @@ def make_serve_step(cfg, *, stack_mode: str = "unroll"):
                 positions=positions,
                 caches=caches,
                 peft=peft,
-                stack_mode=stack_mode,
             )
         else:
             logits, _, caches = model_apply(
@@ -173,7 +157,6 @@ def make_serve_step(cfg, *, stack_mode: str = "unroll"):
                 positions=positions,
                 caches=caches,
                 peft=peft,
-                stack_mode=stack_mode,
             )
         logits = logits[:, -1]
         next_token = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)

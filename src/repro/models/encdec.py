@@ -60,7 +60,6 @@ def encode(
     drops=None,
     peft: Optional[Sequence] = None,
     lora_scale: float = 1.0,
-    stack_mode: str = "unroll",
 ):
     """frames: (B, S_enc, d) stub embeddings -> (B, S_enc, d) encoder states."""
     compute_dtype = jnp.dtype(cfg.dtype)
@@ -77,7 +76,6 @@ def encode(
         drops=drops,
         peft=peft,
         lora_scale=lora_scale,
-        stack_mode=stack_mode,
     )
     return _norm_apply(cfg, params["encoder"]["final_norm"], h)
 
@@ -105,7 +103,6 @@ def decode(
     caches=None,
     peft: Optional[Sequence] = None,
     lora_scale: float = 1.0,
-    stack_mode: str = "unroll",
     remat: bool = False,
     tail: Optional[int] = None,
 ):
@@ -130,7 +127,6 @@ def decode(
         enc_kvs=enc_kvs,
         peft=peft,
         lora_scale=lora_scale,
-        stack_mode=stack_mode,
         remat=remat,
     )
     if tail is not None:

@@ -13,7 +13,7 @@ leading ``(L, ...)`` layer axis — whenever the stack is homogeneous;
 heterogeneous stacks (hybrid interleaves) keep the per-layer list layout.
 ``stack_params``/``unstack_params`` (re-exported from
 :mod:`repro.models.stacking`) convert between the two for the
-heterogeneous/hetlora and dry-run ``unroll`` paths.
+heterogeneous and hetlora paths.
 """
 from __future__ import annotations
 
@@ -47,20 +47,13 @@ def model_apply(
     positions=None,
     peft=None,
     lora_scale: float = 1.0,
-    stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
     tail=None,
 ):
     if cfg.is_encoder_decoder:
         if enc_kvs is None:
-            enc_out = encdec.encode(
-                params,
-                cfg,
-                batch["frames"],
-                peft=None,
-                stack_mode=stack_mode if stack_mode in ("unroll", "scan") else "unroll",
-            )
+            enc_out = encdec.encode(params, cfg, batch["frames"], peft=None)
             enc_kvs = encdec.encoder_cross_kvs(params, cfg, enc_out)
         return encdec.decode(
             params,
@@ -72,7 +65,6 @@ def model_apply(
             caches=caches,
             peft=peft,
             lora_scale=lora_scale,
-            stack_mode=stack_mode if stack_mode in ("unroll", "scan") else "unroll",
             remat=remat,
             tail=tail,
         )
@@ -87,15 +79,8 @@ def model_apply(
         caches=caches,
         peft=peft,
         lora_scale=lora_scale,
-        stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
         tail=tail,
     )
 
-
-def default_stack_mode(cfg) -> str:
-    """Preferred training stack mode per family (dry-run overrides to unroll)."""
-    if cfg.family == "hybrid":
-        return "group"
-    return "scan"

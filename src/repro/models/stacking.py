@@ -14,8 +14,8 @@ Two on-host layouts exist for "a stack of L per-layer pytrees":
 Stacked is the native layout everywhere the stack is *homogeneous* (every
 layer has identical structure and shapes).  Heterogeneous stacks — hybrid
 attn/mamba interleaves, MoE-every-other-layer patterns — keep the list
-layout, which ``stack_apply``'s ``unroll``/``group`` modes consume as
-before.  All library entry points accept either layout; these helpers are
+layout, which ``stack_apply`` stacks per slot of ``cfg.layer_period`` at
+trace time.  All library entry points accept either layout; these helpers are
 the single place layout decisions live.
 """
 from __future__ import annotations

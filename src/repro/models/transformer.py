@@ -4,25 +4,15 @@ Layer stacks arrive in either layout (see :mod:`repro.models.stacking`):
 **stacked** — one pytree with a leading ``(L, ...)`` layer axis on every
 leaf, the native layout for homogeneous stacks — or **list** — one pytree
 per layer, kept for heterogeneous stacks (hybrid interleaves) and legacy
-callers.  ``scan``/``gather``/``group`` consume a stacked tree directly
-(zero ``jnp.stack`` inside the traced program); a list is stacked at trace
-time as before.
+callers.
 
-Stack execution modes (``stack_mode``):
-
-* ``unroll`` — python loop over layers (per-layer slices of a stacked
-  tree).  Used by the dry-run so ``cost_analysis`` counts every layer (a
-  ``lax.scan`` body is costed once — measured 10x undercount, see DESIGN.md
-  §8) and by heterogeneous stacks.
-* ``scan``   — ``lax.scan`` over the stacked layer params (homogeneous
-  stacks): fast compiles for deep models; the training default.
-* ``group``  — ``lax.scan`` over groups of ``cfg.layer_period`` layers
-  (Jamba's mamba/attn/MoE interleave repeats with period 8).
-* ``gather`` — gather-STLD (core.stld): static active count, traced indices,
-  a pure ``jnp.take`` on the stacked leaves, scan over the sub-stack.
-
-STLD gating (``drops``) composes with ``unroll``/``scan``/``group``;
-``gather`` replaces it with index sampling.
+``stack_apply`` runs every stack with one ``lax.scan`` over groups of
+``cfg.layer_period`` layers (Jamba's mamba/attn/MoE interleave repeats with
+period 8; every other family has period 1).  The body applies a group's
+layers in order.  A stacked tree at period 1 is scanned as it is (zero
+``jnp.stack`` inside the traced program); a list is stacked at trace time.
+STLD gates each layer with a real ``cond`` (``drops``), or, in gather-STLD
+(``active_idx``), the scan runs over a ``jnp.take`` of the kept layers.
 """
 from __future__ import annotations
 
@@ -36,24 +26,6 @@ from repro.models import stacking
 from repro.models.layers import init_layer, init_layer_cache, layer_apply, layer_kind
 from repro.nn.initializers import normal_init
 from repro.nn.norms import apply_layernorm, apply_rmsnorm, init_layernorm, init_rmsnorm
-
-_EMPTY = object()  # sentinel for absent scan inputs
-
-
-def _stack(trees: Sequence):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
-def _as_stacked(trees):
-    """Stacked tree for scan-family modes: pass-through when already
-    stacked, trace-time stack for list-layout callers."""
-    return trees if stacking.is_stacked(trees) else _stack(list(trees))
-
-
-def _homogeneous(trees) -> bool:
-    if stacking.is_stacked(trees):
-        return True
-    return stacking.is_stackable(list(trees))
 
 
 def _norm_init(cfg, dim):
@@ -128,23 +100,39 @@ def stack_apply(
     enc_kvs: Optional[Sequence] = None,
     peft: Optional[Sequence] = None,
     lora_scale: float = 1.0,
-    stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
 ):
     """Run the layer stack.  Returns (h, aux_sum, new_caches).
 
-    ``layers``/``peft``/``enc_kvs`` accept either layout: a per-layer list
-    or a stacked tree with a leading layer axis.  ``remat`` rematerializes
-    each layer in the backward pass; with ``drops`` the gate and the block
-    are rematerialized as one unit (:func:`repro.core.stld.gate_remat`), so
-    a layer saves only its input, a dropped layer costs no backward work,
-    and no gradient reaches the layer weights.  A ``cond`` around a
-    checkpointed block would save the checkpoint's inputs, the layer's
-    weights among them, and a layer scan would stack them into a copy of
-    every layer's weights.
+    One ``lax.scan`` runs over groups of ``cfg.layer_period`` layers; its
+    body applies the group's layers in order.  ``layers``/``peft``/
+    ``caches``/``enc_kvs`` accept either layout: a per-layer list or a
+    stacked tree with a leading layer axis.  A stacked tree at period 1 is
+    scanned as it is; above period 1 it is reshaped to ``(groups, period,
+    ...)`` and split into its slots, and a list is stacked per slot at trace
+    time.  Caches come out in the layout they came in.
+
+    ``drops`` gates each layer with a real ``cond``
+    (:func:`repro.core.stld.gate`).  ``active_idx`` (gather-STLD, period 1
+    only) scans the ``jnp.take`` of the kept layers instead; gathering *is*
+    the dropout, so ``drops`` is ignored.
+
+    ``remat`` rematerializes each layer in the backward pass; with ``drops``
+    the gate and the block are rematerialized as one unit
+    (:func:`repro.core.stld.gate_remat`), so a layer saves only its input, a
+    dropped layer costs no backward work, and no gradient reaches the layer
+    weights.  A ``cond`` around a checkpointed block would save the
+    checkpoint's inputs, the layer's weights among them, and a layer scan
+    would stack them into a copy of every layer's weights.
     """
     num_layers = stacking.stack_size(layers)
+    if not num_layers:
+        return h, jnp.zeros((), dtype=jnp.float32), caches
+    period = cfg.layer_period
+    if num_layers % period:
+        raise ValueError("the layer loop requires num_layers % layer_period == 0")
+    n_groups = num_layers // period
 
     def layer(p_l, peft_l, enc_kv_l, h, cache_l, drop):
         # every array the block reads is an argument, so that the remat
@@ -176,143 +164,54 @@ def stack_apply(
             return fn(h, cache_l)
         return stld.gate(fn, drop, h, cache_l)
 
-    # ---------------------------------------------------------- unroll
-    if stack_mode == "unroll":
-        aux_sum = jnp.zeros((), dtype=jnp.float32)
-        caches_stacked = caches is not None and stacking.is_stacked(caches)
-        new_caches = [] if caches is not None else None
-        for l in range(num_layers):
-            cache_l = stacking.layer_view(caches, l) if caches is not None else None
-            peft_l = stacking.layer_view(peft, l) if peft is not None else None
-            enc_kv_l = stacking.layer_view(enc_kvs, l) if enc_kvs is not None else None
-            p_l = stacking.layer_view(layers, l)
-            drop = drops[l] if drops is not None else None
-            h, aux, cache_l = layer(p_l, peft_l, enc_kv_l, h, cache_l, drop)
-            aux_sum = aux_sum + aux
-            if new_caches is not None:
-                new_caches.append(cache_l)
-        if caches_stacked:
-            new_caches = stacking.stack_params(new_caches)
-        return h, aux_sum, new_caches
-
-    # -------------------------------------------------- gather_unroll
-    # gather-STLD with a python loop over the k gathered layers: same
-    # compiled semantics as "gather", but every block appears in the HLO so
-    # cost_analysis is exact (a lax.scan body is costed once — DESIGN.md §8).
-    if stack_mode == "gather_unroll":
-        if not _homogeneous(layers):
-            raise ValueError("gather_unroll requires a homogeneous stack")
-        assert active_idx is not None, "gather_unroll needs active_idx"
-        stacked = _as_stacked(layers)
-        peft_s = _as_stacked(peft) if peft is not None else None
-        take = lambda tree, i: jax.tree.map(lambda x: x[i], tree)
-        aux_sum = jnp.zeros((), dtype=jnp.float32)
-        for j in range(active_idx.shape[0]):
-            idx = active_idx[j]
-            p_l = take(stacked, idx)
-            peft_l = take(peft_s, idx) if peft_s is not None else None
-            h, aux, _ = layer(p_l, peft_l, None, h, None, None)
-            aux_sum = aux_sum + aux
-        return h, aux_sum, None
-
-    # ------------------------------------------------------ scan / gather
-    if stack_mode in ("scan", "gather"):
-        if not _homogeneous(layers):
-            raise ValueError(f"stack_mode={stack_mode!r} requires a homogeneous stack")
-        cols = {
-            "params": _as_stacked(layers),
-            "peft": _as_stacked(peft) if peft is not None else _EMPTY,
-            "caches": _as_stacked(caches) if caches is not None else _EMPTY,
-            "enc": _as_stacked(enc_kvs) if enc_kvs is not None else _EMPTY,
-            "drops": drops if drops is not None else _EMPTY,
-        }
-        if stack_mode == "gather":
-            assert active_idx is not None, "gather mode needs active_idx"
-            cols["drops"] = _EMPTY  # gathering *is* the dropout
-            for name in ("params", "peft", "caches", "enc"):
-                if cols[name] is not _EMPTY:
-                    cols[name] = jax.tree.map(
-                        lambda x: jnp.take(x, active_idx, axis=0), cols[name]
-                    )
-        order = [k for k, v in cols.items() if v is not _EMPTY]
-        xs = tuple(cols[k] for k in order)
-
-        def body(h, xs_vals):
-            v = dict(zip(order, xs_vals))
-            h, aux, new_cache = layer(
-                v["params"], v.get("peft"), v.get("enc"), h, v.get("caches"), v.get("drops")
-            )
-            return h, (aux, new_cache if caches is not None else jnp.zeros((0,)))
-
-        h, (auxs, new_caches_s) = jax.lax.scan(body, h, xs)
-        aux_sum = jnp.sum(auxs)
-        if caches is None:
-            return h, aux_sum, None
-        if stacking.is_stacked(caches):
-            # stacked in, stacked out: the scan's (L, ...) output IS the
-            # stacked layout — no per-layer unstack in the traced program
-            return h, aux_sum, new_caches_s
-        new_caches = [jax.tree.map(lambda x: x[i], new_caches_s) for i in range(num_layers)]
-        return h, aux_sum, new_caches
-
-    # ------------------------------------------------------------- group
-    if stack_mode == "group":
-        period = cfg.layer_period
-        if num_layers % period:
-            raise ValueError("group mode requires num_layers % layer_period == 0")
-        n_groups = num_layers // period
-
-        def by_slot(seq):
-            if stacking.is_stacked(seq):
-                # stacked (L, ...) leaves: a (n_groups, period) reshape + slot
-                # slice replaces the trace-time per-slot jnp.stack
-                grouped = jax.tree.map(
-                    lambda x: x.reshape((n_groups, period) + x.shape[1:]), seq
-                )
-                return tuple(
-                    jax.tree.map(lambda x: x[:, s], grouped) for s in range(period)
-                )
+    def by_slot(seq):
+        """A column as ``period`` trees with a leading group axis; slot
+        ``s`` holds layers ``s, s + period, ...``."""
+        if not stacking.is_stacked(seq):
             seq = list(seq)
-            return tuple(
-                _stack([seq[g * period + s] for g in range(n_groups)])
-                for s in range(period)
+            return tuple(stacking.stack_params(seq[s::period]) for s in range(period))
+        if period == 1:
+            return (seq,)
+        grouped = jax.tree.map(lambda x: x.reshape((n_groups, period) + x.shape[1:]), seq)
+        return tuple(jax.tree.map(lambda x: x[:, s], grouped) for s in range(period))
+
+    cols = {"params": layers, "peft": peft, "caches": caches, "enc": enc_kvs, "drops": drops}
+    if active_idx is not None:
+        if period > 1:
+            raise ValueError("gather-STLD requires a homogeneous stack (layer_period 1)")
+        cols["drops"] = None  # gathering *is* the dropout
+    order = [k for k, v in cols.items() if v is not None]
+    xs = tuple(by_slot(cols[k]) for k in order)
+    if active_idx is not None:
+        xs = jax.tree.map(lambda x: jnp.take(x, active_idx, axis=0), xs)
+
+    def body(h, xs_slots):
+        v = dict(zip(order, xs_slots))
+        aux_sum, out_caches = None, []
+        for s in range(period):
+            col = lambda k: v[k][s] if k in v else None
+            h, aux, cache_l = layer(
+                col("params"), col("peft"), col("enc"), h, col("caches"), col("drops")
             )
+            aux_sum = aux if aux_sum is None else aux_sum + aux
+            out_caches.append(cache_l)
+        return h, (aux_sum, tuple(out_caches) if caches is not None else None)
 
-        cols = {
-            "params": by_slot(layers),
-            "peft": by_slot(peft) if peft is not None else _EMPTY,
-            "caches": by_slot(caches) if caches is not None else _EMPTY,
-            "drops": drops.reshape(n_groups, period) if drops is not None else _EMPTY,
-        }
-        order = [k for k, v in cols.items() if v is not _EMPTY]
-        xs = tuple(cols[k] for k in order)
-
-        def gbody(h, xs_vals):
-            v = dict(zip(order, xs_vals))
-            aux_sum = jnp.zeros((), dtype=jnp.float32)
-            out_caches = []
-            for s in range(period):
-                cache_l = v["caches"][s] if "caches" in v else None
-                peft_l = v["peft"][s] if "peft" in v else None
-                drop = v["drops"][s] if "drops" in v else None
-                h, aux, cache_l = layer(v["params"][s], peft_l, None, h, cache_l, drop)
-                aux_sum = aux_sum + aux
-                out_caches.append(cache_l if cache_l is not None else jnp.zeros((0,)))
-            return h, (aux_sum, tuple(out_caches))
-
-        h, (auxs, new_slot_caches) = jax.lax.scan(gbody, h, xs)
-        aux_sum = jnp.sum(auxs)
-        if caches is None:
-            return h, aux_sum, None
-        new_caches = []
-        for g in range(n_groups):
-            for s in range(period):
-                new_caches.append(jax.tree.map(lambda x: x[g], new_slot_caches[s]))
-        if stacking.is_stacked(caches):
-            new_caches = stacking.stack_params(new_caches)
-        return h, aux_sum, new_caches
-
-    raise ValueError(f"unknown stack_mode {stack_mode!r}")
+    h, (auxs, new_slots) = jax.lax.scan(body, h, xs)
+    aux_sum = jnp.sum(auxs)
+    if caches is None:
+        return h, aux_sum, None
+    if not stacking.is_stacked(caches):
+        return h, aux_sum, [
+            jax.tree.map(lambda x: x[l // period], new_slots[l % period])
+            for l in range(num_layers)
+        ]
+    if period == 1:
+        # stacked in, stacked out: the scan's (L, ...) output is the layout
+        return h, aux_sum, new_slots[0]
+    return h, aux_sum, jax.tree.map(
+        lambda *xs: jnp.stack(xs, axis=1).reshape((num_layers,) + xs[0].shape[1:]), *new_slots
+    )
 
 
 # --------------------------------------------------------------------------
@@ -329,7 +228,6 @@ def lm_apply(
     caches=None,
     peft=None,
     lora_scale: float = 1.0,
-    stack_mode: str = "unroll",
     active_idx=None,
     remat: bool = False,
     tail: Optional[int] = None,
@@ -358,7 +256,6 @@ def lm_apply(
         caches=caches,
         peft=peft,
         lora_scale=lora_scale,
-        stack_mode=stack_mode,
         active_idx=active_idx,
         remat=remat,
     )

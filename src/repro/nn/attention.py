@@ -4,14 +4,14 @@ Two execution paths:
 
 * ``naive`` — full (Sq, Skv) score matrix.  Used when the score tensor is
   small enough; FLOP-exact for ``cost_analysis``.
-* ``q-blocked`` — python loop over query blocks (NOT ``lax.scan``) so the
-  dry-run's ``cost_analysis`` still counts every block.  Bounds the transient
-  score tensor for 32k-prefill shapes.
+* ``q-blocked`` — python loop over query blocks (NOT ``lax.scan``) so
+  ``cost_analysis`` still counts every block.  Bounds the transient score
+  tensor for 32k-prefill shapes.
 
 The Pallas flash-attention kernel (``repro.kernels.flash_attention``) is the
 TPU production path, selected with ``attention_impl='pallas'``; the XLA paths
-here are the portable reference used for CPU smoke tests and dry-run
-lowering (Pallas does not lower to the CPU backend).
+here are the portable reference used for CPU smoke tests (Pallas does not
+lower to the CPU backend).
 """
 from __future__ import annotations
 

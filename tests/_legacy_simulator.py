@@ -30,7 +30,7 @@ from repro.data import DeviceDataset, dirichlet_partition, make_task
 from repro.federated import server as server_lib
 from repro.federated.client import make_client_fns
 from repro.federated.system_model import SystemModel, sample_bandwidth, sample_device
-from repro.models.registry import default_stack_mode, init_params
+from repro.models.registry import init_params
 from repro.optim import adamw_init
 
 
@@ -133,10 +133,8 @@ class FederatedSimulator:
         self.base_params = init_params(k1, cfg, layout="list")
         self.global_peft = peft_lib.init_peft(k2, cfg, peft_cfg, layout="list")
         self.device_peft: Dict[int, list] = {}
-        stack_mode = default_stack_mode(cfg)
         self.client = make_client_fns(
-            cfg, peft_cfg, stld_cfg, train_cfg, stack_mode=stack_mode,
-            loss_tail=self.task.loss_tail,
+            cfg, peft_cfg, stld_cfg, train_cfg, loss_tail=self.task.loss_tail,
         )
         self.local_round, self.evaluate = self.client.local_round, self.client.evaluate
         # server aggregation is pure tree math: jit it so a round's
@@ -179,8 +177,7 @@ class FederatedSimulator:
             for r in set(self.device_rank):
                 pc = peft_cfg.__class__(**{**peft_cfg.__dict__, "lora_rank": r})
                 self._het_fns[r] = make_client_fns(
-                    cfg, pc, stld_cfg, train_cfg, stack_mode=stack_mode,
-                    loss_tail=self.task.loss_tail,
+                    cfg, pc, stld_cfg, train_cfg, loss_tail=self.task.loss_tail,
                 )
 
     # ------------------------------------------------------------------ run

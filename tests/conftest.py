@@ -1,8 +1,8 @@
 import jax
 import pytest
 
-# Smoke tests and benches must see the single real CPU device (the 512-device
-# override lives ONLY inside launch/dryrun.py, which runs as its own process).
+# Smoke tests and benches see the single real CPU device: nothing here sets a
+# host device count.
 
 
 @pytest.fixture

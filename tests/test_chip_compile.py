@@ -160,7 +160,7 @@ def _compile_cohort_round_eval(one_chip, arch, batch, seq):
         i32(4),
     )
     fns = make_client_fns(
-        cfg, pcfg, STLDConfig(mode="cond"), TrainConfig(), stack_mode="scan", donate=True,
+        cfg, pcfg, STLDConfig(mode="cond"), TrainConfig(), donate=True,
         loss_tail=SyntheticTask.loss_tail,
     )
     return fns.cohort_round_eval.lower(*args).compile(), _nbytes(args)

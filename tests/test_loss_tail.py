@@ -80,7 +80,7 @@ def test_tail_logits_and_gradients_equal_every_position(arch):
     batch = _model_batch(cfg, b["tokens"])
 
     def loss(peft, tail):
-        logits, _, _ = model_apply(base, cfg, batch, peft=peft, stack_mode="scan", tail=tail)
+        logits, _, _ = model_apply(base, cfg, batch, peft=peft, tail=tail)
         targets, mask = b["targets"], b["mask"]
         if tail is not None:
             targets, mask = targets[:, -tail:], mask[:, -tail:]
@@ -122,7 +122,7 @@ def test_local_round_with_the_tail_equals_every_position(arch, mode, steps):
     outs = {}
     for tail in (None, task.loss_tail):
         fns = make_client_fns(
-            cfg, _PEFT, stld_cfg, _TRAIN, stack_mode="scan", donate=False, loss_tail=tail
+            cfg, _PEFT, stld_cfg, _TRAIN, donate=False, loss_tail=tail
         )
         peft_out, _, metrics, importance = fns.local_round(
             base, peft, adamw_init(peft), batches, jnp.float32(0.5),

@@ -99,7 +99,7 @@ def test_batched_mixed_adapters_match_per_request_switching(key):
     for name, tree in trees.items():
         reg.register(name, tree)
     pool = AdapterPoolCache(reg, n_slots=2)
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
 
     B = 3
     prompts = [[5, 7, 11], [13, 17], [19, 23, 29, 31]]
@@ -147,7 +147,7 @@ def test_hot_swap_mid_generation_matches_solo(key, budgets):
     reg = AdapterRegistry()
     for i in range(3):
         reg.register(f"t{i}", trees[f"client{i % 2}"])
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
 
     def serve_all(requests, batch):
         pool = AdapterPoolCache(reg, n_slots=2)
@@ -182,7 +182,7 @@ def test_admission_defers_until_slot_free(key):
     reg = AdapterRegistry()
     for i in range(3):
         reg.register(f"t{i}", trees[f"client{i % 2}"])
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
 
     def serve_all(requests):
         pool = AdapterPoolCache(reg, n_slots=2)
@@ -216,7 +216,7 @@ def test_batcher_guards(key):
     reg = AdapterRegistry()
     for i in range(3):
         reg.register(f"t{i}", trees[f"client{i % 2}"])
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
 
     def make(pool):
         return ContinuousBatcher(
@@ -264,7 +264,7 @@ def test_checkpoint_roundtrip_identical_logits(key, tmp_path):
     loaded = AdapterRegistry().load_checkpoint(str(tmp_path))
     assert sorted(loaded.names()) == ["client0", "client1"]
 
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
     B = 2
     token = jnp.asarray([[5], [7]], jnp.int32)
     pos = jnp.zeros((B,), jnp.int32)
@@ -288,7 +288,7 @@ def test_adapter_hot_swap_zero_recompiles(key):
     for i in range(4):  # 4 tenants, 2 slots -> every rotation hot-swaps
         reg.register(f"t{i}", trees[f"client{i % 2}"])
     pool = AdapterPoolCache(reg, n_slots=2)
-    serve = make_serve_step(cfg, stack_mode="scan")
+    serve = make_serve_step(cfg)
     batcher = ContinuousBatcher(
         serve, params, cfg, pool, batch=2, max_len=16, cache_dtype=jnp.float32
     )

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import PEFTConfig, TrainConfig, get_config
-from repro.models import init_params, model_apply
+from repro.models import init_params
 from repro.nn.moe import init_moe, moe_apply
 
 
@@ -45,17 +45,7 @@ def test_moe_weight_gather_grads_flow(key):
     assert any(float(jnp.abs(l).max()) > 0 for l in jax.tree.leaves(g))
 
 
-def test_gather_unroll_equals_gather_scan(key):
-    cfg = get_config("qwen3-1.7b", smoke=True).replace(num_layers=4, dtype="float32")
-    params = init_params(key, cfg)
-    batch = {"tokens": jax.random.randint(key, (2, 8), 0, cfg.vocab_size)}
-    idx = jnp.array([0, 3])
-    ls, _, _ = model_apply(params, cfg, batch, stack_mode="gather", active_idx=idx)
-    lu, _, _ = model_apply(params, cfg, batch, stack_mode="gather_unroll", active_idx=idx)
-    np.testing.assert_allclose(ls, lu, atol=1e-5)
-
-
-def test_train_step_gather_unroll_mode(key):
+def test_train_step_gather_mode(key):
     from repro.core import peft as peft_lib
     from repro.launch.steps import make_train_step
     from repro.optim import adamw_init
@@ -64,9 +54,7 @@ def test_train_step_gather_unroll_mode(key):
     pcfg = PEFTConfig(method="lora", lora_rank=2)
     params = init_params(key, cfg)
     peft = peft_lib.init_peft(key, cfg, pcfg)
-    step = make_train_step(
-        cfg, pcfg, TrainConfig(), stld_mode="gather", mean_rate=0.5, stack_mode="unroll"
-    )
+    step = make_train_step(cfg, pcfg, TrainConfig(), stld_mode="gather", mean_rate=0.5)
     batch = {"tokens": jax.random.randint(key, (2, 9), 0, cfg.vocab_size)}
     new_peft, _, metrics = jax.jit(step)(params, peft, adamw_init(peft), batch, key)
     assert bool(jnp.isfinite(metrics["loss"]))

@@ -1,7 +1,7 @@
 """Sharding rule engine: divisibility fallbacks across the 10 archs.
 
 These tests exercise spec_for_param / cache_specs directly (no devices
-needed); the 512-device lowering proof lives in launch/dryrun.py.
+needed).
 """
 import jax
 import jax.numpy as jnp

@@ -121,7 +121,7 @@ def test_peft_layout_round_trip(method, key):
 def _client_setup(layout, stld_mode="cond"):
     pcfg = PEFTConfig(method="lora", lora_rank=2)
     scfg = STLDConfig(mode=stld_mode, mean_rate=0.5, gather_bucket=1)
-    fns = make_client_fns(_CFG, pcfg, scfg, _TRAIN, stack_mode="scan", donate=False)
+    fns = make_client_fns(_CFG, pcfg, scfg, _TRAIN, donate=False)
     key = jax.random.PRNGKey(0)
     base = init_params(key, _CFG, layout=layout)
     peft = peft_lib.init_peft(key, _CFG, pcfg, layout=layout)
@@ -245,8 +245,8 @@ def test_donation_safe_round_trip():
     only engage on GPU/TPU, where donation is real."""
     pcfg = PEFTConfig(method="lora", lora_rank=2)
     scfg = STLDConfig(mode="cond", mean_rate=0.5)
-    fns_d = make_client_fns(_CFG, pcfg, scfg, _TRAIN, stack_mode="scan", donate=True)
-    fns_n = make_client_fns(_CFG, pcfg, scfg, _TRAIN, stack_mode="scan", donate=False)
+    fns_d = make_client_fns(_CFG, pcfg, scfg, _TRAIN, donate=True)
+    fns_n = make_client_fns(_CFG, pcfg, scfg, _TRAIN, donate=False)
     key = jax.random.PRNGKey(0)
     base = init_params(key, _CFG)
     peft = peft_lib.init_peft(key, _CFG, pcfg)

@@ -97,7 +97,7 @@ def test_gather_equals_cond_for_same_active_set(key):
     batch = {"tokens": jax.random.randint(key, (2, 8), 0, cfg.vocab_size)}
     active = jnp.array([0, 2])
     drops = jnp.array([False, True, False, True])
-    lg, _, _ = model_apply(params, cfg, batch, stack_mode="gather", active_idx=active)
+    lg, _, _ = model_apply(params, cfg, batch, active_idx=active)
     lc, _, _ = model_apply(params, cfg, batch, drops=drops)
     np.testing.assert_allclose(lg, lc, atol=1e-5)
 
@@ -113,9 +113,7 @@ def test_gather_grads_zero_for_dropped_layers(key):
     active = jnp.array([1, 3])
 
     def loss(pf):
-        lo, _, _ = model_apply(
-            params, cfg, batch, peft=pf, stack_mode="gather", active_idx=active
-        )
+        lo, _, _ = model_apply(params, cfg, batch, peft=pf, active_idx=active)
         return jnp.mean(lo**2)
 
     from repro.models import stacking
@@ -151,7 +149,7 @@ def test_gated_remat_scan_saves_no_copy_of_the_weights(key):
     def loss(peft):
         out, _, _ = stack_apply(
             layers, cfg, h, positions=jnp.arange(16), drops=drops, peft=peft,
-            lora_scale=2.0, stack_mode="scan", remat=True,
+            lora_scale=2.0, remat=True,
         )
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
@@ -160,15 +158,18 @@ def test_gated_remat_scan_saves_no_copy_of_the_weights(key):
     assert temp < weights, (temp, weights)
 
 
-@pytest.mark.parametrize("stack_mode", ["scan", "unroll"])
-def test_remat_gate_matches_the_plain_gate(key, stack_mode):
+@pytest.mark.parametrize(
+    "arch", ["qwen3-1.7b", "jamba-v0.1-52b"], ids=["period1", "period2-jamba"]
+)
+def test_remat_gate_matches_the_plain_gate(key, arch):
     """Rematerialized as one unit, the gated stack gives the plain gated
-    stack's loss and adapter gradients; dropped layers get none."""
+    stack's loss and adapter gradients; dropped layers get none.  Jamba's
+    period of 2 runs each gate inside a group of the layer loop."""
     from repro.configs import PEFTConfig
     from repro.core import peft as peft_lib
     from repro.models import stacking
 
-    cfg = get_config("qwen3-1.7b", smoke=True).replace(num_layers=4, dtype="float32")
+    cfg = get_config(arch, smoke=True).replace(num_layers=4, dtype="float32")
     params = init_params(key, cfg)
     peft = peft_lib.init_peft(key, cfg, PEFTConfig(method="lora", lora_rank=2))
     peft = jax.tree.map(lambda x: x + 0.01, peft)  # every factor off zero
@@ -176,9 +177,7 @@ def test_remat_gate_matches_the_plain_gate(key, stack_mode):
     drops = jnp.array([False, True, False, True])
 
     def loss(pf, remat):
-        lo, _, _ = model_apply(
-            params, cfg, batch, peft=pf, drops=drops, stack_mode=stack_mode, remat=remat
-        )
+        lo, _, _ = model_apply(params, cfg, batch, peft=pf, drops=drops, remat=remat)
         return jnp.mean(lo**2)
 
     (l0, g0), (l1, g1) = (jax.value_and_grad(loss)(peft, r) for r in (False, True))
@@ -186,7 +185,8 @@ def test_remat_gate_matches_the_plain_gate(key, stack_mode):
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
     for l in (1, 3):
-        assert all(float(jnp.abs(x).max()) == 0.0 for x in jax.tree.leaves(stacking.layer_view(g1, l)))
+        g_l = jax.tree.leaves(stacking.layer_view(g1, l))
+        assert g_l and all(float(jnp.abs(x).max()) == 0.0 for x in g_l)
     with pytest.raises(ValueError, match="frozen"):
         jax.grad(lambda p: model_apply(
-            p, cfg, batch, drops=drops, stack_mode=stack_mode, remat=True)[0].sum())(params)
+            p, cfg, batch, drops=drops, remat=True)[0].sum())(params)

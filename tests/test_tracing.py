@@ -163,8 +163,7 @@ def _cohort_round_eval_text(runner):
     engine.client = engine.client._replace(cohort_round_eval=keep)
     runner.run(rounds=1)
     a, kw = seen["args"]
-    client = make_client_fns(
-        _CFG, _PEFT, runner.ctx.stld_cfg, _TRAIN, stack_mode=engine.stack_mode)
+    client = make_client_fns(_CFG, _PEFT, runner.ctx.stld_cfg, _TRAIN)
     return client.cohort_round_eval.lower(*a, **kw).compile().as_text()
 
 
