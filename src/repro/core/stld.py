@@ -130,49 +130,181 @@ def gate(block_fn: Callable, drop, h, cache=None):
     return jax.lax.cond(drop, skip_branch, active_branch, (h, cache))
 
 
-def gate_remat(block_fn: Callable, drop, h, frozen, trained):
-    """:func:`gate` for training, rematerialized as one unit.
+# what the kept block keeps across the gate: the outputs of its weight
+# products (matmuls with no batch dimension), not the attention core's
+# batched einsums, which the backward recomputes with the elementwise work
+_SAVE_PRODUCTS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
 
-    ``block_fn(h, frozen, trained) -> (h', aux)``.  The forward runs the gate
-    and saves only its inputs.  The backward is one ``cond``: the kept
-    branch recomputes the block and takes its VJP in the same branch, the
-    dropped branch passes the cotangent of ``h`` through.  A dropped layer
-    so costs nothing either way, and no weight or activation crosses a
-    branch boundary: ``jax.checkpoint`` around :func:`gate` would split the
-    backward into a recompute ``cond`` and a VJP ``cond``, the first writing
-    every weight slice and activation the second reads (zeros, when the
-    layer is dropped).  ``frozen`` (the layer's weights) takes no gradient,
-    and differentiating it raises; ``trained`` (its adapters) does.
+
+def _kept_block(block_fn):
+    """``kept(h, frozen, trained) -> ((h', aux), saved)``: the block's
+    output and its pullback's residuals less the block's own inputs, which
+    the backward has already; ``spec`` (filled in when ``kept`` is traced)
+    rebuilds the pullback from the inputs and ``saved``."""
+    spec = {}
+
+    def kept(h, frozen, trained):
+        # no optimization barrier around the recomputation's inputs: it runs
+        # in its own backward ``cond``, with nothing to be merged with, and a
+        # barrier would make the backward copy every weight and saved slice
+        block = jax.checkpoint(
+            lambda hh, tt: block_fn(hh, frozen, tt), policy=_SAVE_PRODUCTS, prevent_cse=False
+        )
+        out, pullback = jax.vjp(block, h, trained)
+        inputs = {id(x): i for i, x in enumerate(jax.tree.leaves((h, frozen, trained)))}
+        leaves, spec["tree"] = jax.tree.flatten(pullback)
+        spec["slots"], saved = [], []
+        for x in leaves:
+            if id(x) in inputs:
+                spec["slots"].append(("input", inputs[id(x)]))
+            elif isinstance(x, jax.Array):
+                spec["slots"].append(("saved", len(saved)))
+                saved.append(x)
+            else:
+                spec["slots"].append(("static", x))
+        return out, saved
+
+    def pullback(h, frozen, trained, saved):
+        inputs = jax.tree.leaves((h, frozen, trained))
+        pick = {"input": inputs.__getitem__, "saved": saved.__getitem__, "static": lambda x: x}
+        return jax.tree.unflatten(spec["tree"], [pick[k](v) for k, v in spec["slots"]])
+
+    return kept, pullback
+
+
+def saved_bytes(block_fn, h, frozen, trained) -> int:
+    """Bytes :func:`scan_remat` keeps across one gate beyond the layer's
+    input (the outputs of the block's weight products), reckoned from shapes
+    (``jax.ShapeDtypeStruct`` or arrays; nothing runs)."""
+    kept, _ = _kept_block(block_fn)
+    _, saved = jax.eval_shape(kept, h, frozen, trained)
+    return sum(x.size * x.dtype.itemsize for x in saved)
+
+
+def scan_remat(block_fn: Callable, drops, h, frozen, shared, trained):
+    """The layer loop of :func:`gate`-ed layers for training, with the kept
+    layers' products saved in place across the gates.
+
+    One ``lax.scan`` runs over groups of ``len(drops)`` layers; each layer
+    runs ``block_fn(h, (frozen_l, shared), trained_l) -> (h', aux)``.
+    ``drops``, ``frozen`` and ``trained`` are per-slot tuples of trees with
+    a leading group axis (slot ``s`` holds layers ``s, s + period, ...``);
+    ``shared`` (the positions) is every layer's.  Returns ``(h, aux summed
+    over layers)``.
+
+    The forward scan carries, per slot, one buffer per saved array with a
+    leading group axis: each layer's input and the outputs of its weight
+    products (q, k, v, o, gate, up and the LoRA projections), what its VJP
+    reads.  Each gate is one ``cond`` that takes the buffers as operands:
+    the kept branch runs the block and writes its input and products into
+    the group's slot in place; the dropped branch passes ``h`` and the
+    buffers through, writing nothing.  The backward scan runs the groups in
+    reverse, each gate one ``cond``: the kept branch reads its slot and
+    replays the block's VJP from it, recomputing only elementwise work and
+    the attention core, with no weight product of the forward; the dropped
+    branch passes the cotangent of ``h`` through.  A dropped layer so costs
+    no matmul and no residual traffic either way.
+
+    No weight leaves a ``cond``: the layer's weights and adapters reach
+    the backward as the scan's slices, and what is computed from the
+    weights alone (their casts) is recomputed there.  Residuals returned by
+    a ``cond`` would be copied into the scan's stacked outputs, and a
+    ``cond`` around a checkpointed block would stack a copy of every
+    layer's weights.  ``frozen`` and ``shared`` take no gradient, and
+    differentiating them raises; ``trained`` (the adapters) does.
     """
+    period = len(drops)
+    # one per slot: each traces its own slot's layer structure
+    blocks = [_kept_block(block_fn) for _ in range(period)]
 
-    def run(drop, h, frozen, trained):
-        h_new, aux, _ = gate(lambda hh, cc: (*block_fn(hh, frozen, trained), cc), drop, h)
-        return h_new, aux
+    def run(drops, h, frozen, shared, trained):
+        def body(h, xs):
+            d, fz, tr = xs
+            auxs = []
+            for s in range(period):
+                step = lambda hh, cc, fz=fz[s], tr=tr[s]: (*block_fn(hh, (fz, shared), tr), cc)
+                h, aux, _ = gate(step, d[s], h)
+                auxs.append(aux)
+            return h, sum(auxs)
 
-    def fwd(drop, h, frozen, trained):
-        if any(p.perturbed for p in jax.tree.leaves(frozen)):
-            raise ValueError("the frozen layer weights take no gradient through gate_remat")
-        res = jax.tree.map(lambda p: p.value, (drop, h, frozen, trained))
-        return run(*res), res
+        h, auxs = jax.lax.scan(body, h, (drops, frozen, trained))
+        return h, jnp.sum(auxs)
+
+    def fwd(drops, h, frozen, shared, trained):
+        if any(p.perturbed for p in jax.tree.leaves((frozen, shared))):
+            raise ValueError("the frozen layer weights take no gradient through scan_remat")
+        drops, h, frozen, shared, trained = jax.tree.map(
+            lambda p: p.value, (drops, h, frozen, shared, trained)
+        )
+        n_groups = drops[0].shape[0]
+        layer = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), tree)
+        shapes = [
+            jax.eval_shape(kept, h, (layer(frozen[s]), shared), layer(trained[s]))
+            for s, (kept, _) in enumerate(blocks)
+        ]
+        bufs = tuple(
+            [jax.lax.empty((n_groups,) + x.shape, x.dtype) for x in [h, *saved]]
+            for _, saved in shapes
+        )
+
+        def body(carry, xs):
+            h, bufs = carry
+            g, d, fz, tr = xs
+            bufs, auxs = list(bufs), []
+            for s, (kept, _) in enumerate(blocks):
+                (_, aux), _ = shapes[s]
+
+                def kept_branch(h, fz, shared, tr, buf, g, kept=kept):
+                    (h_new, aux), saved = kept(h, (fz, shared), tr)
+                    buf = [jax.lax.dynamic_update_index_in_dim(b, x, g, 0)
+                           for b, x in zip(buf, [h, *saved])]
+                    return h_new, aux, buf
+
+                def dropped_branch(h, fz, shared, tr, buf, g, aux=aux):
+                    return h, jnp.zeros(aux.shape, aux.dtype), buf
+
+                # the layer's inputs are operands, so that the kept branch sees
+                # them as its own and leaves them out of what it saves
+                h, aux, bufs[s] = jax.lax.cond(
+                    d[s], dropped_branch, kept_branch, h, fz[s], shared, tr[s], bufs[s], g
+                )
+                auxs.append(aux)
+            return (h, tuple(bufs)), sum(auxs)
+
+        xs = (jnp.arange(n_groups), drops, frozen, trained)
+        (h, bufs), auxs = jax.lax.scan(body, (h, bufs), xs)
+        return (h, jnp.sum(auxs)), (drops, frozen, shared, trained, bufs)
 
     def bwd(res, cts):
-        drop, h, frozen, trained = res
-        cts = jax.tree.map(
+        drops, frozen, shared, trained, bufs = res
+        dh, daux = jax.tree.map(
             lambda c: jnp.zeros(c.aval.shape, c.aval.dtype) if isinstance(c, SymbolicZero) else c,
             cts,
             is_leaf=lambda c: isinstance(c, SymbolicZero),
         )
 
-        def kept(cts):
-            _, vjp = jax.vjp(lambda hh, tt: block_fn(hh, frozen, tt), h, trained)
-            return vjp(cts)
+        def body(dh, xs):
+            g, d, fz, tr = xs
+            dtr = [None] * period
+            for s in reversed(range(period)):
+                _, pullback = blocks[s]
 
-        def dropped(cts):
-            return cts[0], jax.tree.map(jnp.zeros_like, trained)
+                def kept_ct(dh, fz, shared, tr, buf, g, pullback=pullback):
+                    h, *saved = [jax.lax.dynamic_index_in_dim(b, g, 0, keepdims=False) for b in buf]
+                    return pullback(h, (fz, shared), tr, saved)((dh, daux))
 
-        dh, dtrained = jax.lax.cond(drop, dropped, kept, cts)
-        return None, dh, None, dtrained
+                def dropped_ct(dh, fz, shared, tr, buf, g):
+                    return dh, jax.tree.map(jnp.zeros_like, tr)
+
+                dh, dtr[s] = jax.lax.cond(
+                    d[s], dropped_ct, kept_ct, dh, fz[s], shared, tr[s], bufs[s], g
+                )
+            return dh, tuple(dtr)
+
+        xs = (jnp.arange(drops[0].shape[0]), drops, frozen, trained)
+        dh, dtrained = jax.lax.scan(body, dh, xs, reverse=True)
+        return None, dh, None, None, dtrained
 
     gated = jax.custom_vjp(run)
     gated.defvjp(fwd, bwd, symbolic_zeros=True)
-    return gated(drop, h, frozen, trained)
+    return gated(tuple(drops), h, tuple(frozen), shared, tuple(trained))

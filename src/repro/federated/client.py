@@ -89,14 +89,16 @@ def make_client_fns(
     names reach the compiled ops' ``op_name`` metadata, where a profiler
     trace finds each phase's device time, and change nothing else.
 
-    Every training step rematerializes each layer in its backward pass, so
-    the saved activations are one layer input per layer.  With STLD enabled
-    every program gates each layer with a real ``cond``, so a dropped layer
-    costs no forward, recompute or backward; with it disabled the layers run
-    ungated.  The cohort programs train their devices one after another
-    inside the one program, since under ``vmap`` a ``cond`` whose predicate
-    differs per device becomes a select that runs every layer.  The fused
-    validation is vmapped over the trained trees.
+    Every training step rematerializes its layers in the backward pass.
+    With STLD enabled every program gates each layer with a real ``cond``
+    (``stld.scan_remat``): a kept layer saves its input and the outputs of
+    its weight products, so its backward runs no forward matmul, and a
+    dropped layer costs no forward or backward.  With STLD disabled the
+    layers run ungated and save only their inputs.  The cohort programs
+    train their devices one after another inside the one program, since
+    under ``vmap`` a ``cond`` whose predicate differs per device becomes a
+    select that runs every layer.  The fused validation is vmapped over the
+    trained trees.
 
     ``loss_tail`` (static; ``None`` keeps every position) states that the
     training loss falls on the last ``loss_tail`` positions only: the final

@@ -44,10 +44,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core import peft as peft_lib
 from repro.core import stld as stld_lib
 from repro.federated import server as server_lib
 from repro.federated.client import make_client_fns
-from repro.models import stacking
+from repro.models import registry, stacking
 from repro.optim import adamw_init
 
 
@@ -101,6 +102,7 @@ class CohortEngine:
         # FedHetLoRA: per-device LoRA rank + per-rank client programs
         self.device_rank: Optional[List[int]] = None
         self._het_fns: Dict[int, object] = {}
+        self._saved_bytes: Optional[int] = None
 
     def enable_hetlora(self, device_rank: List[int]):
         """Build per-rank client programs for rank-heterogeneous cohorts."""
@@ -226,6 +228,25 @@ class CohortEngine:
         seq = self.task.seq_len + (self.cfg.frontend_seq if self.cfg.modality == "vision" else 0)
         return fed.batch_size * seq
 
+    def saved_activation_bytes_per_step(self) -> int:
+        """Bytes one client step keeps across its STLD gates: every layer's
+        kept-branch products (``stld.scan_remat``) at the step's batch,
+        reckoned from shapes once; 0 where the gates do not run (gather
+        mode, or the STLD config off)."""
+        if self._saved_bytes is None:
+            self._saved_bytes = 0
+            if self.stld_cfg.enabled and self.stld_cfg.mode != "gather":
+                tokens = jax.ShapeDtypeStruct(
+                    (self.fed_cfg.batch_size, self.task.seq_len), jnp.int32
+                )
+                peft = jax.eval_shape(
+                    lambda: peft_lib.init_peft(jax.random.PRNGKey(0), self.cfg, self.peft_cfg)
+                )
+                self._saved_bytes = registry.gated_saved_bytes(
+                    self.base_params, self.cfg, tokens, peft=peft
+                )
+        return self._saved_bytes
+
     def _run_cohort_batched(
         self, cohort, rates, start_pefts, keys, gsteps, num_classes, adaopt_depth
     ):
@@ -233,7 +254,8 @@ class CohortEngine:
 
         Host spans, inside the round's own: ``round.stage`` (stacking
         and host-to-device copies), ``round.dispatch`` (enqueueing the
-        program; arg ``head_rows``, :meth:`head_positions_per_step`) and
+        program; args ``head_rows``, :meth:`head_positions_per_step`, and
+        ``saved_bytes``, :meth:`saved_activation_bytes_per_step`) and
         ``round.pull`` (the unstack and the one ``device_get`` that waits
         for it)."""
         n = len(cohort)
@@ -260,7 +282,11 @@ class CohortEngine:
                     jnp.asarray(np.stack([v[k] for v in val_list]))
                     for k in ("tokens", "labels", "valid")
                 )
-            with obs.span("round.dispatch", head_rows=self.head_positions_per_step()):
+            with obs.span(
+                "round.dispatch",
+                head_rows=self.head_positions_per_step(),
+                saved_bytes=self.saved_activation_bytes_per_step(),
+            ):
                 if adaopt:
                     # progressive depth discards deep-layer updates before
                     # eval, so train and eval cannot be fused: train,

@@ -17,6 +17,8 @@ heterogeneous and hetlora paths.
 """
 from __future__ import annotations
 
+import jax
+
 from repro.models import encdec, transformer
 from repro.models.stacking import (  # noqa: F401  (public converter API)
     is_stacked,
@@ -84,3 +86,28 @@ def model_apply(
         tail=tail,
     )
 
+
+def gated_saved_bytes(params, cfg, tokens, *, peft=None) -> int:
+    """Bytes :func:`model_apply` with ``drops`` and ``remat`` keeps across
+    its layers' STLD gates for ``tokens`` (B, S) and the modality's stub
+    frontend: :func:`transformer.gated_saved_bytes` at the stack's input.
+    Reckoned from shapes (arrays or ``jax.ShapeDtypeStruct``s); nothing
+    runs."""
+    b, seq = tokens.shape
+    if cfg.is_encoder_decoder:
+        layers = params["decoder"]["layers"]
+        frames = jax.ShapeDtypeStruct((b, cfg.frontend_seq, cfg.d_model), cfg.dtype)
+        enc_kvs = jax.eval_shape(
+            lambda p, f: encdec.encoder_cross_kvs(p, cfg, encdec.encode(p, cfg, f)),
+            params,
+            frames,
+        )
+    else:
+        layers, enc_kvs = params["layers"], None
+        if cfg.modality == "vision":
+            seq += cfg.frontend_seq
+    h = jax.ShapeDtypeStruct((b, seq, cfg.d_model), cfg.dtype)
+    positions = jax.ShapeDtypeStruct((seq,), "int32")
+    return transformer.gated_saved_bytes(
+        layers, cfg, h, positions=positions, enc_kvs=enc_kvs, peft=peft
+    )

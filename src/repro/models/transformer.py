@@ -88,6 +88,48 @@ def init_caches(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, layout: str =
 # --------------------------------------------------------------------------
 # stack execution
 # --------------------------------------------------------------------------
+def _layer_block(cfg, causal: bool, lora_scale: float):
+    """``block(h, (params, enc_kv, positions), peft, cache=None)``: one
+    layer.  Every array the block reads is an argument, so that the remat
+    gate's backward closes over none."""
+
+    def block(hh, frozen, pf, cc=None):
+        p, enc_kv, pos = frozen
+        return layer_apply(
+            p,
+            cfg,
+            hh,
+            positions=pos,
+            causal=causal,
+            cache=cc,
+            enc_kv=enc_kv,
+            peft=pf,
+            lora_scale=lora_scale,
+        )
+
+    return block
+
+
+def gated_saved_bytes(layers, cfg, h, *, positions, enc_kvs=None, peft=None) -> int:
+    """Bytes one pass of a causal :func:`stack_apply` with ``drops`` and
+    ``remat`` keeps across its gates: every layer's kept-branch products
+    (:func:`repro.core.stld.saved_bytes`), reckoned from the shapes of
+    ``h`` (the stack's input), ``positions``, the layers and their adapters;
+    nothing runs.  Beyond what a checkpointed, ungated stack keeps (each
+    layer's input); a dropped layer's slot is held but never written."""
+    block = _layer_block(cfg, True, 1.0)
+    train = lambda hh, fz, pf: block(hh, fz, pf)[:2]
+    view = lambda tree, l: (
+        None if tree is None else jax.eval_shape(lambda t: stacking.layer_view(t, l), tree)
+    )
+    period = cfg.layer_period
+    per_group = sum(
+        stld.saved_bytes(train, h, (view(layers, s), view(enc_kvs, s), positions), view(peft, s))
+        for s in range(period)
+    )
+    return stacking.stack_size(layers) // period * per_group
+
+
 def stack_apply(
     layers: Sequence,
     cfg,
@@ -118,13 +160,12 @@ def stack_apply(
     only) scans the ``jnp.take`` of the kept layers instead; gathering *is*
     the dropout, so ``drops`` is ignored.
 
-    ``remat`` rematerializes each layer in the backward pass; with ``drops``
-    the gate and the block are rematerialized as one unit
-    (:func:`repro.core.stld.gate_remat`), so a layer saves only its input, a
-    dropped layer costs no backward work, and no gradient reaches the layer
-    weights.  A ``cond`` around a checkpointed block would save the
-    checkpoint's inputs, the layer's weights among them, and a layer scan
-    would stack them into a copy of every layer's weights.
+    ``remat`` rematerializes each layer in the backward pass.  With
+    ``drops`` the gate and the block go through
+    :func:`repro.core.stld.scan_remat`: a kept layer saves its input and the
+    outputs of its weight products (:func:`gated_saved_bytes` reckons them),
+    so the backward runs no forward matmul; a dropped layer costs no work
+    either way, and no gradient reaches the layer weights.
     """
     num_layers = stacking.stack_size(layers)
     if not num_layers:
@@ -134,29 +175,10 @@ def stack_apply(
         raise ValueError("the layer loop requires num_layers % layer_period == 0")
     n_groups = num_layers // period
 
-    def layer(p_l, peft_l, enc_kv_l, h, cache_l, drop):
-        # every array the block reads is an argument, so that the remat
-        # gate's backward closes over none
-        def block(hh, frozen, pf, cc=None):
-            p, enc_kv, pos = frozen
-            return layer_apply(
-                p,
-                cfg,
-                hh,
-                positions=pos,
-                causal=causal,
-                cache=cc,
-                enc_kv=enc_kv,
-                peft=pf,
-                lora_scale=lora_scale,
-            )
+    block = _layer_block(cfg, causal, lora_scale)
 
+    def layer(p_l, peft_l, enc_kv_l, h, cache_l, drop):
         frozen = (p_l, enc_kv_l, positions)
-        if drop is not None and remat:
-            if cache_l is not None:
-                raise ValueError("remat with STLD gates runs without caches")
-            train = lambda hh, fz, pf: block(hh, fz, pf)[:2]
-            return (*stld.gate_remat(train, drop, h, frozen, peft_l), None)
         fn = lambda hh, cc: block(hh, frozen, peft_l, cc)
         if remat:
             fn = jax.checkpoint(fn)
@@ -181,6 +203,20 @@ def stack_apply(
             raise ValueError("gather-STLD requires a homogeneous stack (layer_period 1)")
         cols["drops"] = None  # gathering *is* the dropout
     order = [k for k, v in cols.items() if v is not None]
+    if cols["drops"] is not None and remat:
+        if caches is not None:
+            raise ValueError("remat with STLD gates runs without caches")
+        slots = lambda k: by_slot(cols[k]) if cols[k] is not None else (None,) * period
+        train = lambda hh, fz, pf: block(hh, (*fz[0], fz[1]), pf)[:2]
+        h, aux_sum = stld.scan_remat(
+            train,
+            slots("drops"),
+            h,
+            tuple(zip(slots("params"), slots("enc"))),
+            positions,
+            slots("peft"),
+        )
+        return h, aux_sum, None
     xs = tuple(by_slot(cols[k]) for k in order)
     if active_idx is not None:
         xs = jax.tree.map(lambda x: jnp.take(x, active_idx, axis=0), xs)

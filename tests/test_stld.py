@@ -190,3 +190,43 @@ def test_remat_gate_matches_the_plain_gate(key, arch):
     with pytest.raises(ValueError, match="frozen"):
         jax.grad(lambda p: model_apply(
             p, cfg, batch, drops=drops, remat=True)[0].sum())(params)
+
+
+def _weight_products(jaxpr) -> int:
+    """``dot_general``s without batch dimensions (the weight products) in
+    ``jaxpr`` and every jaxpr inside it."""
+    from repro.analysis.jaxpr_contracts import walk_eqns
+
+    return sum(
+        1
+        for eqn in walk_eqns(jaxpr)
+        if eqn.primitive.name == "dot_general"
+        and not any(eqn.params["dimension_numbers"][1])
+    )
+
+
+def test_gated_remat_backward_runs_no_forward_weight_product(key):
+    """The gated, rematerialized stack's gradient has as many weight
+    products as the plain gated stack's, forward plus backward: the
+    backward replays the kept block's VJP from its saved products and
+    recomputes none of them.  (The attention core's batched einsums may be
+    recomputed and are not counted.)"""
+    from repro.configs import PEFTConfig
+    from repro.core import peft as peft_lib
+
+    cfg = get_config("qwen3-1.7b", smoke=True).replace(num_layers=4, dtype="float32")
+    params = init_params(key, cfg)
+    peft = peft_lib.init_peft(key, cfg, PEFTConfig(method="lora", lora_rank=2))
+    batch = {"tokens": jax.random.randint(key, (2, 8), 0, cfg.vocab_size)}
+    drops = jnp.array([False, True, False, True])
+
+    def products(remat):
+        loss = lambda pf: jnp.mean(
+            model_apply(params, cfg, batch, peft=pf, drops=drops, remat=remat)[0] ** 2
+        )
+        return _weight_products(jax.make_jaxpr(jax.grad(loss))(peft))
+
+    # per kept layer: q, k, v, o, gate, up, down and two LoRA factors on
+    # each of q and v forward (11); the input products of the seven and
+    # four per LoRA projection backward (15); the head forward and back
+    assert products(True) == products(False) == 28

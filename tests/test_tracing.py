@@ -74,7 +74,7 @@ def test_init_span_carries_the_parameter_bytes(tmp_path):
 
 
 def test_each_round_span_holds_its_phases_in_order(traced):
-    _, spans = traced
+    runner, spans = traced
     rounds = [s for s in spans if s[0] == "repro.round"]
     assert [s[3] for s in rounds] == [{"round": 0}, {"round": 1}]
     for _, r0, r1, _ in rounds:
@@ -82,9 +82,13 @@ def test_each_round_span_holds_its_phases_in_order(traced):
         assert [s[0] for s in inside] == [f"repro.round.{p}" for p in PHASES]
         # the phases take their round from the span around them; the
         # dispatch carries the head's rows per client step (the synthetic
-        # task's tail of 1 on each of the batch's rows)
+        # task's tail of 1 on each of the batch's rows) and the bytes a
+        # client step keeps across its gates
         args = {s[0]: s[3] for s in inside}
-        assert args.pop("repro.round.dispatch") == {"head_rows": _FED.batch_size}
+        assert args.pop("repro.round.dispatch") == {
+            "head_rows": _FED.batch_size,
+            "saved_bytes": runner.ctx.engine.saved_activation_bytes_per_step(),
+        }
         assert all(a == {} for a in args.values())
     # the evaluation that ends the run call follows the rounds
     (evaluate,) = [s for s in spans if s[0] == "repro.evaluate"]
@@ -114,6 +118,32 @@ def test_kept_layers_within_the_bodies_run(traced):
 def test_layer_bodies_per_step_follows_the_gating_mode(mode, cohort_mode, rate, bodies):
     engine = _runner(rate, mode=mode, cohort_mode=cohort_mode).ctx.engine
     assert engine.layer_bodies_per_step(rate) == bodies
+
+
+@pytest.mark.parametrize("mode", ["cond", "gather"])
+def test_dispatch_span_carries_the_saved_bytes(tmp_path, mode):
+    """``repro.round.dispatch`` carries ``saved_bytes``, the engine's
+    reckoning of what a client step keeps across its STLD gates: every
+    layer's products under the cond gates at rate 0.5, nothing in gather
+    mode, which runs no gate."""
+    runner = _runner(0.5, mode=mode)
+    with jax.profiler.trace(str(tmp_path)):
+        runner.run(rounds=1)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    (dispatch,) = [dict(e.stats) for plane in ProfileData.from_file(str(path)).planes
+                   if plane.name.startswith("/host")
+                   for line in plane.lines for e in line.events
+                   if e.name == "repro.round.dispatch"]
+    saved = runner.ctx.engine.saved_activation_bytes_per_step()
+    assert dispatch["saved_bytes"] == saved
+    # cond: q, k, v, o, gate, up and both factors of the q and v LoRA
+    # products of each layer, over the step's rows, in float32
+    b, s, r = _FED.batch_size, runner.ctx.engine.task.seq_len, _PEFT.lora_rank
+    hd = _CFG.resolved_head_dim
+    q, kv = _CFG.num_heads * hd, _CFG.num_kv_heads * hd
+    widths = (q, kv, kv, _CFG.d_model, _CFG.d_ff, _CFG.d_ff) + (r, q) + (r, kv)
+    per_layer = b * s * 4 * sum(widths)
+    assert saved == (_CFG.num_layers * per_layer if mode == "cond" else 0)
 
 
 class _EveryPosition(SyntheticTask):
